@@ -108,6 +108,9 @@ def run(quick: bool = True):
     if not quick or os.environ.get("REPRO_ABC_DRYRUN", "1") == "1":
         env = dict(os.environ)
         env["PYTHONPATH"] = "src:."
+        # the dry run is 512 forced host devices: keep the child off any
+        # accelerator this process already holds
+        env["JAX_PLATFORMS"] = "cpu"
         out = subprocess.run(
             [sys.executable, "-c", _DRYRUN_CODE], env=env, capture_output=True,
             text=True, timeout=900,

@@ -32,6 +32,7 @@ import json
 import sys
 from pathlib import Path
 
+import jax
 
 sys.path.insert(0, str(Path(__file__).resolve().parent))
 from _harness import (  # noqa: E402
@@ -133,7 +134,9 @@ def tile_study(args, cells, parity, rows):
                      num_days=DAYS, backend="pallas", model=model)
     cache = tuning.TuningCache()
     cache.put(tuning.cfg_cache_key(cfg0), {
-        "schema": tuning.CACHE_SCHEMA, "backend": "pallas", "model": model,
+        "schema": tuning.CACHE_SCHEMA,
+        "device_kind": jax.devices()[0].device_kind,
+        "backend": "pallas", "model": model,
         "days": DAYS, "batch": args.batch, "summary": "identity",
         "distance": "euclidean", "schedule": "nosched",
         "tile": int(best), "best_batch": None,

@@ -14,6 +14,8 @@ import time
 import traceback
 from pathlib import Path
 
+from repro.compile_cache import enable_compile_cache
+
 BENCHES = {
     "table1": ("benchmarks.bench_runtime", "Table 1: runtime vs tolerance/accepted"),
     "table2_3": ("benchmarks.bench_batch_sweep", "Tables 2-3: batch-size sweep"),
@@ -79,6 +81,7 @@ def main(argv=None):
                          "their default settings, then copies the fresh "
                          "artifacts over the baselines)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.refresh:
         sys.exit(refresh_baselines())
     only = set(args.only.split(",")) if args.only else set(BENCHES)

@@ -42,6 +42,7 @@ from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.extend.core import ClosedJaxpr, Jaxpr
 import numpy as np
 
 from repro.analysis.report import Finding
@@ -94,13 +95,11 @@ def _walk_jaxprs(jaxpr) -> Iterable:
     for eqn in jaxpr.eqns:
         for val in eqn.params.values():
             for sub in jax.tree_util.tree_leaves(
-                val, is_leaf=lambda x: isinstance(
-                    x, (jax.core.Jaxpr, jax.core.ClosedJaxpr)
-                )
+                val, is_leaf=lambda x: isinstance(x, (Jaxpr, ClosedJaxpr))
             ):
-                if isinstance(sub, jax.core.ClosedJaxpr):
+                if isinstance(sub, ClosedJaxpr):
                     yield from _walk_jaxprs(sub.jaxpr)
-                elif isinstance(sub, jax.core.Jaxpr):
+                elif isinstance(sub, Jaxpr):
                     yield from _walk_jaxprs(sub)
 
 

@@ -499,17 +499,24 @@ def wave_loop_body(
     capacity: int,
     *,
     fold_axis=None,  # device index to fold into the run key (shard_map path)
-    count_all=None,  # per-wave local count -> global count (psum under shard_map)
+    count_all=None,  # per-shard fill -> global total (psum under shard_map)
 ):
     """One wave: sample -> simulate -> compare -> compact into the buffer.
 
     Returns a `body(carry)` for `lax.while_loop` with carry
     `(wave, n_global, fill, theta_buf, dist_buf)`; the extra run inputs
-    (key, run_idx0, tolerance, data) are closed over by the caller via
-    `functools.partial`-style nesting in `build_wave_loop`.
+    (key, run_idx0, tolerance, data, and `n_base` under `count_all`) are
+    closed over by the caller via `functools.partial`-style nesting in
+    `build_wave_loop`.
+
+    Under `count_all` the global count is re-derived every wave as
+    `n_base + count_all(fill)`, never accumulated from per-wave increments:
+    XLA's TPU compiler moves an all-reduce whose result only feeds a
+    loop-carried sum out of the loop without looking at the loop condition,
+    so the stop test would read each shard's local count.
     """
 
-    def body(carry, key, run_idx0, tolerance, data):
+    def body(carry, key, run_idx0, tolerance, data, n_base=None):
         w, n_global, fill, th_buf, d_buf = carry
         k = jax.random.fold_in(key, run_idx0 + w)
         if fold_axis is not None:
@@ -529,9 +536,11 @@ def wave_loop_body(
         th_buf, d_buf, new_fill = compact_accepted(
             th_buf, d_buf, fill, theta, dist, accept, capacity
         )
-        c_local = new_fill - fill
-        c_global = count_all(c_local) if count_all is not None else c_local
-        return (w + 1, n_global + c_global, new_fill, th_buf, d_buf)
+        if count_all is None:
+            n_global = n_global + (new_fill - fill)
+        else:
+            n_global = n_base + count_all(new_fill)
+        return (w + 1, n_global, new_fill, th_buf, d_buf)
 
     return body
 
@@ -575,13 +584,15 @@ def build_wave_loop(
         max_waves = jnp.asarray(max_waves, jnp.int32)
         n0 = jnp.asarray(n0, jnp.int32)
         fill0 = jnp.asarray(fill0, jnp.int32)
+        # accepts held before this call that no shard's fill counts
+        n_base = None if count_all is None else n0 - count_all(fill0)
 
         def cond(carry):
             w, n_global, *_ = carry
             return jnp.logical_and(n_global < target, w < max_waves)
 
         def body(carry):
-            return body_fn(carry, key, run_idx0, tolerance, data)
+            return body_fn(carry, key, run_idx0, tolerance, data, n_base)
 
         w, n, fill, th_buf, d_buf = jax.lax.while_loop(
             cond, body, (jnp.int32(0), n0, fill0, theta_buf, dist_buf)

@@ -28,21 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# jax >= 0.5 exposes shard_map at the top level; 0.4.x keeps it experimental
-shard_map = getattr(jax, "shard_map", None)
-if shard_map is None:  # pragma: no cover - exercised on jax 0.4.x containers
-    from functools import wraps
-
-    from jax.experimental.shard_map import shard_map as _experimental_shard_map
-
-    @wraps(_experimental_shard_map)
-    def shard_map(*args, **kwargs):
-        # 0.4.x has no replication rule for lax.while_loop (the device wave
-        # loop); jax's documented workaround is to skip the static check.
-        # Our P() outputs are psum-replicated by construction either way.
-        kwargs.setdefault("check_rep", False)
-        return _experimental_shard_map(*args, **kwargs)
-
 from repro.core.abc import (
     ABCConfig,
     RunOutput,
@@ -150,7 +135,7 @@ def make_shardmap_runner(
     local_run = abc_run_batch(prior, simulator, local_cfg)
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=P(),
         out_specs=RunOutput(P(axes), P(axes), P(axes), P()),
@@ -211,7 +196,7 @@ def make_shardmap_wave_runner(
     )
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(), P(), P(axes), P(axes), P(), P(axes), P(), P(), P()),
         out_specs=WaveLoopOutput(P(axes), P(axes), P(), P(), P(axes)),
@@ -281,7 +266,7 @@ def make_shardmap_scenario_runner(
     )
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         # the trailing P() is a pytree-prefix spec: every ScenarioData leaf
         # is replicated across the group

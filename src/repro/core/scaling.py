@@ -268,7 +268,7 @@ def run_scaling_study(
     report: Dict = {
         "config": dataclasses.asdict(scfg),
         "n_visible_devices": len(devices),
-        "device_kind": str(devices[0].platform) if devices else "none",
+        "device_kind": devices[0].device_kind if devices else "none",
         "reference_device_count": n_ref,
         "cells": {},
     }

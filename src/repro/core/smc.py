@@ -173,7 +173,7 @@ def make_sharded_smc_round_fn(mesh, simulator, prior: UniformBoxPrior,
     from jax.sharding import PartitionSpec as P
 
     from repro.core.abc import compact_accepted as _compact
-    from repro.core.distributed import data_axes, shard_map
+    from repro.core.distributed import data_axes
 
     axes = data_axes(mesh)
     n_dev = 1
@@ -189,7 +189,7 @@ def make_sharded_smc_round_fn(mesh, simulator, prior: UniformBoxPrior,
     cap = n_p + B  # a final wave's overshoot always fits per shard
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(), P(), P(), P(), P(), P()),
         out_specs=(P(axes), P(axes), P(), P(), P(axes)),
@@ -216,14 +216,25 @@ def make_sharded_smc_round_fn(mesh, simulator, prior: UniformBoxPrior,
             th_buf, d_buf, new_fill = _compact(
                 th_buf, d_buf, fill, prop, d, d <= eps, cap
             )
-            n = n + jax.lax.psum(new_fill - fill, axes)
+            # the total of the cumulative fills, not a running sum of
+            # increments (see abc.wave_loop_body: XLA would move that
+            # all-reduce out of the loop, past the stop test)
+            n = jax.lax.psum(new_fill, axes)
             return (w + 1, n, new_fill, th_buf, d_buf)
 
-        th0 = jnp.zeros((cap, p), jnp.float32)
-        d0 = jnp.full((cap,), jnp.inf, jnp.float32)
+        # the per-shard fill and buffers vary across the mesh; the wave and
+        # global accept counters stay replicated (psum'd)
+        fill0, th0, d0 = jax.lax.pcast(
+            (
+                jnp.int32(0),
+                jnp.zeros((cap, p), jnp.float32),
+                jnp.full((cap,), jnp.inf, jnp.float32),
+            ),
+            axes,
+            to="varying",
+        )
         w, n, fill, th_buf, d_buf = jax.lax.while_loop(
-            cond, body,
-            (jnp.int32(0), jnp.int32(0), jnp.int32(0), th0, d0),
+            cond, body, (jnp.int32(0), jnp.int32(0), fill0, th0, d0),
         )
         return th_buf, d_buf, n, w, jnp.minimum(fill, cap)[None]
 

@@ -361,10 +361,18 @@ def cache_key(
     summary: str = "identity",
     distance: str = "euclidean",
     schedule=None,
+    device_kind: Optional[str] = None,
 ) -> str:
-    """The tuning-cache key: everything that changes the tuned optimum."""
+    """The tuning-cache key: everything that changes the tuned optimum,
+    the device first (`device_kind=None` reads `jax.devices()[0]`), so a
+    winner measured on one device kind is a miss on another."""
+    if device_kind is None:
+        device_kind = jax.devices()[0].device_kind
     sched = _schedule_shape_tag(model, schedule)
-    return f"{backend}/{model}/d{days}/b{batch}/{summary}/{distance}/{sched}"
+    return (
+        f"{device_kind}/{backend}/{model}/d{days}/b{batch}/{summary}/"
+        f"{distance}/{sched}"
+    )
 
 
 def cfg_cache_key(cfg) -> str:
@@ -526,6 +534,7 @@ def autotune(
 
     entry: Dict = {
         "schema": CACHE_SCHEMA,
+        "device_kind": jax.devices()[0].device_kind,
         "backend": cfg.backend,
         "model": cfg.model,
         "days": cfg.num_days,

@@ -201,8 +201,12 @@ def hazards(
     sc = tuple(st[..., k] for k in range(C))  # each [..., R]
     pc = tuple(theta[..., k : k + 1] for k in range(model.n_params))
     mob = mobility_matrix(model, mobility)
+    # full f32: a TPU runs f32 contractions in one bf16 pass by default,
+    # which would blur the coupled mass to ~3 significant digits
     coupled = tuple(
-        jnp.einsum("rq,...q->...r", mob, st[..., j]) for j in model.coupled_idx
+        jnp.einsum("rq,...q->...r", mob, st[..., j],
+                   precision=jax.lax.Precision.HIGHEST)
+        for j in model.coupled_idx
     )
     rows = model.hazard_rows(sc + coupled, pc, population / R)
     h = jnp.stack(

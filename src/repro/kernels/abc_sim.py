@@ -47,6 +47,16 @@ GLOBAL sample index `idx = lane + TB * tile_idx`, so distances — and the
 accepted particle sets above them — are bit-identical across tile sizes
 (pinned by tests); the tile is pure scheduling.
 
+The observed series lives in SMEM as flat channel-major scalars
+(`[n_chan * num_days]`), and each day reads its `n_chan` values as scalar
+loads at a dynamic offset, which the scalar unit does natively and which
+broadcast to the (1, TB) rows for free. A VMEM block with days on lanes
+needs a one-lane slice at a dynamic lane offset, which Mosaic refuses (it
+cannot prove the offset 128-aligned); days on sublanes would compile, but
+each day would then pay a vector load plus a lane extract and broadcast
+per channel. The series is small (at most 20 channels at the R=10 lane
+budget, times the horizon), so it fits SMEM whole.
+
 The per-day distance accumulation is the traced-select lowering of the
 generalized summary accumulator (repro.core.summaries): the observed block
 arrives PRE-SUMMARIZED, and the channel weights / transform selectors /
@@ -67,6 +77,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.epi import engine
 from repro.epi.spec import CompartmentalModel, ScheduleShape
@@ -152,8 +163,8 @@ def _kernel(
     theta_ref  (Pp, TB)  — params x samples (transposed, sublane-padded);
                            rows n_params.. are window-major intervention
                            scales when `sched` is set
-    obs_ref    (Op, Tp)  — rows 0..n_chan-1 = OBSERVED-SIDE SUMMARY values
-                           per day (running-bin layout, padded; region-major
+    obs_ref    (n_chan * num_days,) in SMEM — OBSERVED-SIDE SUMMARY values,
+                           channel-major (running-bin layout; region-major
                            flattened — or region-pooled when `pool` > 1)
     fconst_ref (1, 128)  — f32 scalars (incl. summary weights / mean scale /
                            row-major mobility matrix for metapop models)
@@ -235,7 +246,6 @@ def _kernel(
     chan0 = tuple(jnp.zeros_like(state0[0]) for _ in range(2 * n_chan))
 
     obs_idx = model.observed_idx
-    n_obs_rows = obs_ref.shape[0]
     ns = R * C
 
     def day_step(day, carry):
@@ -295,7 +305,6 @@ def _kernel(
                 xs.append(x)
         else:
             xs = [sc[g] for g in model.total_observed_idx]
-        obs_t = pl.load(obs_ref, (slice(0, n_obs_rows), pl.dslice(day, 1)))
         flush = jnp.logical_or(
             (day + 1) % bin_days == 0, day == num_days - 1
         ).astype(jnp.float32)
@@ -307,7 +316,7 @@ def _kernel(
             # running within-bin sum (see summaries module docstring)
             b = jnp.where(cumulative == 1, v, binr[m] + v)
             s = jnp.where(use_log1p == 1, jnp.log1p(jnp.maximum(b, 0.0)), b)
-            diff = s - obs_t[m : m + 1]
+            diff = s - obs_ref[m * num_days + day]
             term = jnp.where(power == 1, jnp.abs(diff), diff * diff)
             acc = acc + flush * (weights[m] * term)
             cum[m] = c
@@ -321,7 +330,7 @@ def _kernel(
 
 def abc_sim_distance_kernel(
     theta_t: jax.Array,  # [Pp, B] f32 (transposed, sublane-padded, B % tile == 0)
-    obs_pad: jax.Array,  # [Op, Tp] f32 (rows 0..n_obs-1 = observed channels)
+    obs_flat: jax.Array,  # [n_chan * num_days] f32 (channel-major)
     fconsts: jax.Array,  # [1, 128] f32
     iconsts: jax.Array,  # [1, 128] i32 (seed + breakpoint days)
     *,
@@ -342,8 +351,7 @@ def abc_sim_distance_kernel(
         interpret = auto_interpret()
     p_pad, batch = theta_t.shape
     assert p_pad == sublane_pad(theta_width(model, sched)) and batch % tile == 0
-    o_pad, t_pad = obs_pad.shape
-    assert o_pad == sublane_pad(n_summary_channels(model, pool))
+    assert obs_flat.shape == (n_summary_channels(model, pool) * num_days,)
     grid = (batch // tile,)
     return pl.pallas_call(
         functools.partial(
@@ -353,11 +361,11 @@ def abc_sim_distance_kernel(
         grid=grid,
         in_specs=[
             pl.BlockSpec((p_pad, tile), lambda i: (0, i)),  # theta tile
-            pl.BlockSpec((o_pad, t_pad), lambda i: (0, 0)),  # full obs each cell
+            pl.BlockSpec(memory_space=pltpu.SMEM),  # whole observed series
             pl.BlockSpec((1, _CONST_LANES), lambda i: (0, 0)),
             pl.BlockSpec((1, _CONST_LANES), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((1, tile), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, batch), jnp.float32),
         interpret=interpret,
-    )(theta_t, obs_pad, fconsts, iconsts)
+    )(theta_t, obs_flat, fconsts, iconsts)

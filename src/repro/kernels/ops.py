@@ -12,7 +12,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.core.summaries import N_FLAGS, get_summary, lower_summary, pool_factor
 from repro.epi import engine
@@ -184,12 +183,7 @@ def _abc_sim_distance_jit(
     theta_t = jnp.swapaxes(theta, 0, 1)  # [width, B]
     theta_t = jnp.pad(theta_t, ((0, p_pad - width), (0, pad_b)))
 
-    o_pad = abc_sim.sublane_pad(n_chan)
-    t_pad = int(np.ceil(num_days / 128) * 128)
-    obs_pad = jnp.zeros((o_pad, t_pad), jnp.float32)
-    obs_pad = obs_pad.at[:n_chan, :num_days].set(
-        jnp.asarray(observed, jnp.float32)
-    )
+    obs_flat = jnp.asarray(observed, jnp.float32).reshape(-1)
 
     fconsts = jnp.zeros((1, _CONST_LANES), jnp.float32)
     fconsts = fconsts.at[0, 0].set(population)
@@ -219,7 +213,7 @@ def _abc_sim_distance_jit(
 
     dist = abc_sim.abc_sim_distance_kernel(
         theta_t,
-        obs_pad,
+        obs_flat,
         fconsts,
         iconsts,
         model=model,

@@ -53,7 +53,10 @@ def hash_u32(seed: jnp.ndarray, idx: jnp.ndarray, ctr) -> jnp.ndarray:
 def uniform_open(seed, idx, ctr) -> jnp.ndarray:
     """U in (0, 1]: ((h >> 8) + 1) * 2^-24 — log-safe."""
     h = hash_u32(seed, idx, ctr)
-    return ((h >> np.uint32(8)) + np.uint32(1)).astype(jnp.float32) * _INV_2_24
+    # <= 2^24 fits int32 exactly; the TPU kernel compiler has no
+    # uint32 -> float32 cast, so convert through int32 (same value)
+    u = ((h >> np.uint32(8)) + np.uint32(1)).astype(jnp.int32)
+    return u.astype(jnp.float32) * _INV_2_24
 
 
 def normal(seed, idx, ctr) -> jnp.ndarray:

@@ -51,6 +51,7 @@ from __future__ import annotations
 import argparse
 import json
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.abc import ABCConfig, ABCState, run_abc
 from repro.core.distributed import make_runner, make_wave_runner
 from repro.core.summaries import DISTANCE_KINDS, list_summaries
@@ -401,6 +402,7 @@ def main(argv=None):
     ap.add_argument("--forecast-out", default="",
                     help="path for the forecast JSON (default: stdout)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.regions < 1:
         ap.error("--regions must be >= 1")

@@ -40,6 +40,8 @@ import os
 import sys
 import time
 
+from repro.compile_cache import enable_compile_cache
+
 
 def sweep(server, data_dir: str, models) -> dict:
     """One pass over every dataset file x model; returns status counts."""
@@ -99,6 +101,7 @@ def main(argv=None):
                          "(0 = zero-cost refresh)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     from repro.core.serving import EpiServer, ServeConfig
     from repro.core.smc import SMCConfig

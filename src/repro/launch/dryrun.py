@@ -20,11 +20,11 @@ import time
 import traceback
 from pathlib import Path
 
-import jax  # noqa: F401  (locks the forced device count before other imports)
+import jax  # locks the forced device count before other imports
 
 from repro.ioutils import atomic_write_text
 from repro.launch.analysis import roofline_from_compiled
-from repro.launch.mesh import make_production_mesh, set_mesh_compat
+from repro.launch.mesh import make_production_mesh
 from repro.launch.shapes import SHAPES, SHAPE_ORDER, applicable
 from repro.launch.steps import build_step
 from repro.models.registry import get_model, list_archs
@@ -41,7 +41,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, rules_overrides=None,
     if shape.mode != "train":
         step_kwargs.pop("microbatch", None)
     t0 = time.time()
-    with set_mesh_compat(mesh):
+    with jax.set_mesh(mesh):
         built = build_step(model, mesh, shape, rules_overrides=rules_overrides,
                            **step_kwargs)
         lowered = built.fn.lower(*built.arg_shapes)

@@ -2,12 +2,6 @@
 
 A FUNCTION, not a module-level constant, so importing this module never
 touches jax device state (dry-run contract).
-
-Version compatibility: `jax.sharding.AxisType` only exists on newer jax
-releases (>= 0.5.x); on older versions (e.g. the 0.4.37 in this container)
-`jax.make_mesh` takes no `axis_types` and every axis is implicitly the
-auto-sharded kind we request anyway. `make_compat_mesh` hides the difference
-for every mesh built in this repo (and in tests).
 """
 
 from __future__ import annotations
@@ -15,45 +9,25 @@ from __future__ import annotations
 import jax
 
 
-def axis_types_kwargs(n_axes: int) -> dict:
-    """`{"axis_types": (AxisType.Auto,) * n}` where supported, else `{}`."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:  # jax < 0.5: no explicit axis types; Auto is implied
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
-def make_compat_mesh(shape, axes):
-    """`jax.make_mesh` with Auto axis types where the jax version has them."""
-    shape = tuple(shape)
+def make_auto_mesh(shape, axes):
+    """`jax.make_mesh` with Auto (compiler-sharded) axes; `jax.make_mesh`
+    alone defaults to Explicit axes, which refuse sharding constraints."""
     axes = tuple(axes)
-    return jax.make_mesh(shape, axes, **axis_types_kwargs(len(axes)))
-
-
-def set_mesh_compat(mesh):
-    """Context manager: `jax.set_mesh(mesh)` where it exists, else the mesh.
-
-    `jax.set_mesh` is the >= 0.5.x way to install an ambient mesh; on the
-    0.4.x pin the Mesh object is itself the context manager with the same
-    scoped semantics (it threads the physical mesh through thread_resources,
-    which `models.moe._ambient_mesh` and pjit both read). Every `with
-    jax.set_mesh(...)` in this repo goes through here.
-    """
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
+    return jax.make_mesh(
+        tuple(shape), axes,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 chips per pod; two pods for the multi-pod dry run."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_compat_mesh(shape, axes)
+    return make_auto_mesh(shape, axes)
 
 
 def make_host_mesh(n_devices: int | None = None, model: int = 1):
     """Small mesh over this host's devices (tests / CPU demos)."""
     n = n_devices or len(jax.devices())
     assert n % model == 0
-    return make_compat_mesh((n // model, model), ("data", "model"))
+    return make_auto_mesh((n // model, model), ("data", "model"))

@@ -33,8 +33,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.ioutils import atomic_write_text
-from repro.launch.mesh import make_host_mesh, set_mesh_compat
+from repro.launch.mesh import make_host_mesh
 from repro.models.registry import get_model
 
 
@@ -131,7 +132,7 @@ def run_lm_cli(args):
     vocab = model.cfg.vocab if hasattr(model.cfg, "vocab") else model.cfg.lm.vocab
     cache_len = args.prompt_len + args.gen
 
-    with set_mesh_compat(mesh):
+    with jax.set_mesh(mesh):
         rng = np.random.default_rng(0)
         prompts = [
             rng.integers(0, vocab, size=args.prompt_len).astype(np.int32).tolist()
@@ -261,6 +262,7 @@ def main(argv=None):
                          "forward-pass per query; see core/npe.py)")
     ap.add_argument("--seed", type=int, default=0, help="fit seed (--epi)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.epi:
         if args.arch:

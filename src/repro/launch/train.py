@@ -21,7 +21,7 @@ import jax.numpy as jnp
 
 from repro.checkpoint import Checkpointer
 from repro.data import SyntheticTokenDataset
-from repro.launch.mesh import make_host_mesh, make_production_mesh, set_mesh_compat
+from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.launch.shapes import InputShape
 from repro.launch.steps import build_train_step
 from repro.models.registry import get_model
@@ -55,7 +55,7 @@ def main(argv=None):
     vocab = model.cfg.vocab if hasattr(model.cfg, "vocab") else model.cfg.lm.vocab
     ds = SyntheticTokenDataset(vocab=vocab, seq_len=args.seq, seed=0)
 
-    with set_mesh_compat(mesh):
+    with jax.set_mesh(mesh):
         built = build_train_step(model, mesh, shape, opt_cfg=opt_cfg, donate=True)
         params = model.init_params(jax.random.PRNGKey(0))
         opt_state = adamw_init(params)

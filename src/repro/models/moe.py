@@ -39,24 +39,10 @@ class MoEConfig:
 
 
 def _ambient_mesh():
-    """The mesh in scope, or None — version-guarded.
-
-    Newer jax exposes `jax.sharding.get_abstract_mesh()` (set by
-    `jax.set_mesh` / `use_mesh`); jax < 0.5 has neither, but the physical
-    mesh installed by a `with Mesh(...):` context is available through the
-    thread-resources environment. Either way an empty/absent mesh returns
-    None so constraints are skipped (single-process smoke tests).
-    """
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get is not None:
-        mesh = get()
-    else:  # jax < 0.5: the mesh threaded by `with Mesh(...):`
-        try:
-            from jax._src.mesh import thread_resources
-
-            mesh = thread_resources.env.physical_mesh
-        except Exception:  # very old/new private layout — no ambient mesh
-            return None
+    """The mesh installed by `jax.set_mesh`, or None when no mesh (or an
+    empty one) is in scope, so constraints are skipped (single-process
+    smoke tests)."""
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or not mesh.axis_names:
         return None
     return mesh

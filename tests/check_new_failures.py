@@ -21,12 +21,6 @@ Usage (what CI runs):
     PYTHONPATH=src python tests/check_new_failures.py [extra pytest args]
 
 Extra args are forwarded to pytest (e.g. `-m "not slow"` or a subset path).
-
-`--baseline PATH` (consumed here, never forwarded) selects a different
-known-failures file — the CI jax version matrix keeps one baseline per leg
-(`known_failures.txt` for the 0.4.x pin, `known_failures_jax_latest.txt`
-for latest-release jax), because upstream drift breaks different tests on
-different versions.
 """
 
 from __future__ import annotations
@@ -238,27 +232,9 @@ def narrows_collection(argv) -> bool:
     return False
 
 
-def split_baseline_arg(argv):
-    """Extract our own --baseline option; everything else goes to pytest."""
-    baseline, rest = BASELINE, []
-    it = iter(argv)
-    for a in it:
-        if a == "--baseline":
-            try:
-                baseline = Path(next(it))
-            except StopIteration:
-                raise SystemExit("--baseline requires a path argument")
-        elif a.startswith("--baseline="):
-            baseline = Path(a.split("=", 1)[1])
-        else:
-            rest.append(a)
-    return baseline, rest
-
-
 def main(argv=None) -> int:
     argv = sys.argv[1:] if argv is None else argv
-    baseline, argv = split_baseline_arg(argv)
-    known = load_baseline(baseline)
+    known = load_baseline()
     code, failed = run_pytest(argv)
     return evaluate(known, code, failed, filtered=narrows_collection(argv),
                     confirm_stale=confirm_stale_by_rerun)

@@ -1,12 +1,19 @@
 """Capture R=1 golden outputs for the registered models on all backends.
 
-Run ONCE against the pre-metapop tree (PR 9) to freeze the exact f32
+First run against the pre-metapop tree to freeze the exact f32
 distances each backend produced before the region-axis refactor; the
 committed r1_pins.npz is then asserted bit-identical by
-tests/test_metapop.py::test_r1_bit_identity_pins forever after. Re-running
-this script against a tree whose R=1 paths changed would regenerate (and
-silently launder) the pins — only do that for an intentional, documented
-stream change.
+tests/test_metapop.py::test_r1_bit_identity_pins. Re-running this script
+against a tree whose R=1 paths changed would regenerate (and silently
+launder) the pins — only do that for an intentional, documented stream
+change.
+
+Re-captured once on jax 0.9.0, from the tree before the kernel moved its
+observed series to SMEM (its one `pl.load` rewritten as the equivalent
+`obs_ref[0:n_obs_rows, pl.ds(day, 1)]` read, since jax 0.9.0 has no
+`pl.load`): jax 0.9.0 draws a different theta from the same threefry key
+than the jax 0.4.37 the first capture ran on, and XLA's CPU log/cos differ
+in the last bit, so the old pins failed at their input check.
 
 Usage: PYTHONPATH=src python tests/data/capture_r1_pins.py
 """

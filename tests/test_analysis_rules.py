@@ -306,7 +306,7 @@ def test_suppression_for_other_rule_does_not_suppress():
 # ---------------------------------------------------------------------------
 
 def test_planted_f64_promotion_trips():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         jaxpr = jax.make_jaxpr(
             lambda x: x.astype(jnp.float64) * 2
         )(jnp.zeros(4, jnp.float32))
@@ -316,7 +316,7 @@ def test_planted_f64_promotion_trips():
 
 def test_planted_f64_inside_scan_trips():
     """The walker must recurse into control-flow sub-jaxprs."""
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         def body(c, x):
             return c, x.astype(jnp.float64).sum()
 

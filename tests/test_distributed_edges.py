@@ -64,7 +64,6 @@ import jax, jax.numpy as jnp, numpy as np
 from functools import partial
 from jax.sharding import PartitionSpec as P
 from repro.core.abc import ABCConfig, build_wave_loop, WaveLoopOutput
-from repro.core.distributed import shard_map
 from repro.core.scaling import device_mesh
 from repro.epi.models import get_model
 
@@ -87,7 +86,7 @@ loop = build_wave_loop(
     count_all=lambda c: jax.lax.psum(c, "data"),
 )
 
-@partial(shard_map, mesh=mesh,
+@partial(jax.shard_map, mesh=mesh,
          in_specs=(P(), P(), P("data"), P("data"), P(), P("data"), P(), P(),
                    P()),
          out_specs=WaveLoopOutput(P("data"), P("data"), P(), P(), P("data")))

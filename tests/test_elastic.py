@@ -35,9 +35,9 @@ from repro.models.registry import get_model
 from repro.optim import adamw_init
 
 assert len(jax.devices()) == 8
-from repro.launch.mesh import make_compat_mesh, set_mesh_compat
-mesh = make_compat_mesh((2, 4), ("data", "model"))
-with set_mesh_compat(mesh):
+from repro.launch.mesh import make_auto_mesh
+mesh = make_auto_mesh((2, 4), ("data", "model"))
+with jax.set_mesh(mesh):
     model = get_model("gemma2-27b", smoke=True)
     like_p = model.param_shapes()
     like_o = jax.eval_shape(adamw_init, like_p)

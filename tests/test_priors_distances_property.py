@@ -15,6 +15,9 @@ from _hypothesis_compat import HAVE_HYPOTHESIS, given, settings, st
 from repro.core.distances import DISTANCES
 from repro.core.priors import UniformBoxPrior
 
+# float32 strategies need bounds a float32 holds exactly
+F32_0_01 = float(np.float32(0.01))
+
 # a deliberately lopsided box: zero-width-adjacent, negative lows, big highs
 BOXES = [
     ((1.0, 100.0, 2.0), None),
@@ -55,7 +58,7 @@ def test_prior_log_pdf_finite_exactly_inside(highs, lows):
 @given(
     lows=st.lists(st.floats(-10, 10, allow_nan=False, width=32), min_size=1,
                   max_size=6),
-    widths=st.lists(st.floats(0.01, 20, allow_nan=False, width=32), min_size=1,
+    widths=st.lists(st.floats(F32_0_01, 20, allow_nan=False, width=32), min_size=1,
                     max_size=6),
     seed=st.integers(0, 2**31 - 1),
 )
@@ -108,7 +111,7 @@ def test_distance_permutation_stable_across_batch(name):
 
 @given(
     seed=st.integers(0, 2**31 - 1),
-    scale=st.floats(1e-2, 1e4, allow_nan=False, width=32),
+    scale=st.floats(F32_0_01, 1e4, allow_nan=False, width=32),
 )
 @settings(max_examples=25, deadline=None)
 def test_distance_triangle_like_properties(seed, scale):

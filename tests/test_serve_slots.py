@@ -16,7 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.launch.mesh import make_host_mesh, set_mesh_compat
+from repro.launch.mesh import make_host_mesh
 from repro.launch.serve import _is_axes, run_lm_server, zero_slot
 from repro.models.registry import get_model
 
@@ -49,7 +49,7 @@ def test_mixed_length_batched_matches_single(arch):
     vocab = model.cfg.vocab if hasattr(model.cfg, "vocab") else model.cfg.lm.vocab
     prompts = _prompts(vocab)
     cache_len = max(PROMPT_LENS) + GEN
-    with set_mesh_compat(make_host_mesh()):
+    with jax.set_mesh(make_host_mesh()):
         batched, _ = run_lm_server(model, prompts, GEN, SLOTS, cache_len)
         singles = [
             run_lm_server(model, [p], GEN, slots=1, cache_len=cache_len)[0][0]
@@ -65,7 +65,7 @@ def test_decode_step_vector_pos_matches_scalar():
     """decode_step with a [B] pos vector of one shared value must agree
     with the legacy scalar pos (the lockstep special case)."""
     model = get_model("gemma-2b", smoke=True)
-    with set_mesh_compat(make_host_mesh()):
+    with jax.set_mesh(make_host_mesh()):
         params = model.init_params(jax.random.PRNGKey(0))
         shapes = model.init_cache_shape(2, 8)
         cache = jax.tree.map(
@@ -90,7 +90,7 @@ def test_decode_step_vector_pos_matches_scalar():
 
 def test_zero_slot_clears_only_that_lane():
     model = get_model("zamba2-2.7b", smoke=True)  # kv + ssm + conv leaves
-    with set_mesh_compat(make_host_mesh()):
+    with jax.set_mesh(make_host_mesh()):
         logical = model.cache_logical()
         shapes = model.init_cache_shape(3, 6)
         cache = jax.tree.map(

@@ -51,10 +51,10 @@ from repro.core.abc import ABCConfig, make_simulator
 from repro.core.distributed import make_shardmap_runner, make_pjit_runner
 from repro.core.priors import paper_prior
 from repro.epi.data import get_dataset
-from repro.launch.mesh import make_compat_mesh
+from repro.launch.mesh import make_auto_mesh
 
 assert len(jax.devices()) == 8
-mesh = make_compat_mesh((8,), ("data",))
+mesh = make_auto_mesh((8,), ("data",))
 ds = get_dataset("synthetic_small", num_days=15)
 cfg = ABCConfig(batch_size=8 * 512, tolerance=1.6e4, target_accepted=10**9,
                 chunk_size=128, strategy="outfeed", num_days=15,
@@ -97,8 +97,8 @@ from repro.core.abc import ABCConfig, make_simulator
 from repro.core.distributed import make_shardmap_runner
 from repro.core.priors import paper_prior
 from repro.epi.data import get_dataset
-from repro.launch.mesh import make_compat_mesh
-mesh = make_compat_mesh(({n},), ("data",))
+from repro.launch.mesh import make_auto_mesh
+mesh = make_auto_mesh(({n},), ("data",))
 ds = get_dataset("synthetic_small", num_days=15)
 cfg = ABCConfig(batch_size={n} * 2048, tolerance=1.8e4, target_accepted=10**9,
                 chunk_size=256, num_days=15, backend="xla_fused", max_runs=1)

@@ -16,10 +16,10 @@ from repro.launch.steps import build_train_step, build_prefill_step, build_decod
 from repro.models.registry import get_model
 from repro.optim import adamw_init
 
-from repro.launch.mesh import make_compat_mesh, set_mesh_compat
-mesh = make_compat_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_auto_mesh
+mesh = make_auto_mesh((2, 4), ("data", "model"))
 results = []
-with set_mesh_compat(mesh):
+with jax.set_mesh(mesh):
     for arch in ("gemma2-27b", "qwen3-moe-30b-a3b", "mamba2-130m"):
         model = get_model(arch, smoke=True)
         params = model.init_params(jax.random.PRNGKey(0))

@@ -207,13 +207,19 @@ def test_tile_candidates_respect_divisibility():
 
 def test_cache_key_separates_the_tuning_dimensions():
     keys = {
-        tuning.cache_key(backend=b, model=m, days=d, batch=n)
+        tuning.cache_key(backend=b, model=m, days=d, batch=n, device_kind=k)
+        for k in ("cpu", "TPU v5 lite")
         for b in ("pallas", "xla_fused")
         for m in ("siard", "sir")
         for d in (10, 49)
         for n in (512, 8192)
     }
-    assert len(keys) == 16
+    assert len(keys) == 32
+    # the default key is this process's device: a winner measured on the
+    # CPU interpreter is a miss on a chip
+    assert tuning.cache_key(backend="pallas", model="siard", days=10,
+                            batch=512).startswith(
+        f"{jax.devices()[0].device_kind}/")
 
 
 # --------------------------------------------------------------------------

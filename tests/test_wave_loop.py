@@ -26,6 +26,7 @@ from repro.core.abc import (
 )
 from repro.epi.data import get_dataset
 from repro.epi.models import get_model, list_models
+from repro.launch.mesh import make_auto_mesh
 
 DAYS = 12
 
@@ -144,7 +145,8 @@ def test_pjit_wave_runner_matches_single_device_stream():
     tol = _model_tolerance("siard")
     ds = get_dataset("synthetic_small", num_days=DAYS)
     cfg = _cfg("siard", "xla_fused", tol)
-    mesh = jax.make_mesh((len(jax.devices()),), ("data",))
+    # sharding hints need Auto axes (jax.make_mesh defaults to Explicit)
+    mesh = make_auto_mesh((len(jax.devices()),), ("data",))
     wr = make_dist_wave_runner(mesh, ds, cfg, style="pjit")
     p_pjit = run_abc(ds, cfg, key=0, wave_runner=wr)
     p_single = run_abc(ds, cfg, key=0)
@@ -265,8 +267,6 @@ def test_wave_capacity_reaches_exactly_full(small_dataset):
     assert bool(jnp.all(jnp.isfinite(out.dist_buf)))
 
 
-@pytest.mark.skipif(not hasattr(jax, "shard_map"),
-                    reason="jax.shard_map not available in this jax")
 def test_shardmap_wave_runner_matches_host_distributed_stream():
     """Per-device-replica wave loop vs the legacy shard_map host loop: the
     union of accepted samples must match (ordering differs across shards)."""

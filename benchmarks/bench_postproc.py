@@ -29,7 +29,7 @@ def run(quick: bool = True):
             backend="xla_fused", max_runs=4000,
         )
         post = run_abc(ds, cfg, key=0)
-        pp = getattr(post, "postproc_time_s", 0.0)
+        pp = post.phase_s.get("harvest", 0.0)
         frac = pp / max(post.wall_time_s, 1e-9)
         rows.append([strategy, f"{tol:.2g}", target, len(post),
                      f"{pp*1e3:.1f}", f"{frac:.1%}"])

@@ -50,6 +50,7 @@ with the weights/selectors riding scalar const lanes. The default
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 import zipfile
@@ -503,6 +504,11 @@ def wave_loop_body(
 ):
     """One wave: sample -> simulate -> compare -> compact into the buffer.
 
+    The three steps run under the named scopes `abc.prior`, `abc.simulate`
+    and `abc.accept`, which XLA keeps in each operation's `op_name`
+    metadata, so a device trace can tell them apart whichever simulator
+    backend runs; the key derivation and the stop count stay outside.
+
     Returns a `body(carry)` for `lax.while_loop` with carry
     `(wave, n_global, fill, theta_buf, dist_buf)`; the extra run inputs
     (key, run_idx0, tolerance, data, and `n_base` under `count_all`) are
@@ -522,20 +528,24 @@ def wave_loop_body(
         if fold_axis is not None:
             k = jax.random.fold_in(k, fold_axis())
         k_prior, k_sim = jax.random.split(k)
-        if isinstance(data, ScenarioData):
-            # sample inside the scenario's traced box (bit-identical math to
-            # the baked path) so one compiled loop serves every scenario of
-            # this shape, including swept intervention-scale bounds
-            theta = prior.sample(k_prior, (batch_size,),
-                                 data.prior_lows, data.prior_highs)
-        else:
-            theta = prior.sample(k_prior, (batch_size,))
-        dist = sim_call(theta, k_sim, data)
-        dist = jnp.where(jnp.isnan(dist), jnp.inf, dist)
-        accept = dist <= tolerance
-        th_buf, d_buf, new_fill = compact_accepted(
-            th_buf, d_buf, fill, theta, dist, accept, capacity
-        )
+        with jax.named_scope("abc.prior"):
+            if isinstance(data, ScenarioData):
+                # sample inside the scenario's traced box (bit-identical math
+                # to the baked path) so one compiled loop serves every
+                # scenario of this shape, including swept intervention-scale
+                # bounds
+                theta = prior.sample(k_prior, (batch_size,),
+                                     data.prior_lows, data.prior_highs)
+            else:
+                theta = prior.sample(k_prior, (batch_size,))
+        with jax.named_scope("abc.simulate"):
+            dist = sim_call(theta, k_sim, data)
+        with jax.named_scope("abc.accept"):
+            dist = jnp.where(jnp.isnan(dist), jnp.inf, dist)
+            accept = dist <= tolerance
+            th_buf, d_buf, new_fill = compact_accepted(
+                th_buf, d_buf, fill, theta, dist, accept, capacity
+            )
         if count_all is None:
             n_global = n_global + (new_fill - fill)
         else:
@@ -786,6 +796,17 @@ class ABCState:
         return st
 
 
+@contextlib.contextmanager
+def _phase(phases: dict, name: str, **counters):
+    """One phase of a wave driver: a profiler span `abc.<name>` carrying
+    `counters` (host values already in hand, so the span reads nothing from
+    the device), whose host seconds also add into `phases[name]`."""
+    t0 = time.perf_counter()
+    with jax.profiler.TraceAnnotation(f"abc.{name}", **counters):
+        yield
+    phases[name] = phases.get(name, 0.0) + time.perf_counter() - t0
+
+
 def _harvest(out: RunOutput, cfg: ABCConfig, state: ABCState) -> int:
     """Host-side postprocessing of one run's outputs (paper §3.2 / Table 4).
 
@@ -884,15 +905,16 @@ def run_abc(
         simulator = make_simulator(dataset, cfg)
         run_fn = jax.jit(abc_run_batch(prior, simulator, cfg))
 
-    t0 = time.time()
-    postproc_s = 0.0
+    t0 = time.perf_counter()
+    phases: dict = {}
     while state.n_accepted < cfg.target_accepted and state.run_idx < cfg.max_runs:
         run_key = jax.random.fold_in(key, state.run_idx)
-        out = run_fn(run_key)
-        out = jax.tree.map(jax.block_until_ready, out)
-        tp = time.time()
-        _harvest(out, cfg, state)
-        postproc_s += time.time() - tp
+        with _phase(phases, "wave_loop"):
+            out = run_fn(run_key)
+            out = jax.tree.map(jax.block_until_ready, out)
+        with _phase(phases, "harvest",
+                    sample_days=cfg.batch_size * cfg.num_days):
+            _harvest(out, cfg, state)
         state.run_idx += 1
         state.simulations += cfg.batch_size
         if verbose and state.run_idx % 50 == 0:
@@ -906,21 +928,7 @@ def run_abc(
             and state.run_idx % checkpoint_every == 0
         ):
             state.save(checkpoint_path)
-
-    theta, dist = state.to_arrays()
-    # every harvested sample is returned (a run may overshoot target_accepted;
-    # the paper keeps the overshoot too — callers can slice with Posterior.top)
-    post = Posterior(
-        theta=theta,
-        distances=dist,
-        tolerance=cfg.tolerance,
-        param_names=run_param_names(cfg, spec),
-        runs=state.run_idx,
-        simulations=state.simulations,
-        wall_time_s=time.time() - t0,
-    )
-    post.postproc_time_s = postproc_s  # type: ignore[attr-defined]
-    return post
+    return _posterior(cfg, spec, state, phases, t0)
 
 
 def _run_abc_device(
@@ -940,18 +948,21 @@ def _run_abc_device(
     the buffers come back once. With checkpointing, each segment is bounded
     by `checkpoint_every` waves so a crash loses at most one segment.
     """
-    t0 = time.time()
-    postproc_s = 0.0
-    carry = wave_runner.init(state)
+    t0 = time.perf_counter()
+    phases: dict = {}
+    shard_batch = cfg.batch_size // wave_runner.shards
+    with _phase(phases, "init"):
+        carry = wave_runner.init(state)
     while state.n_accepted < cfg.target_accepted and state.run_idx < cfg.max_runs:
         seg = cfg.max_runs - state.run_idx
         if checkpoint_every and checkpoint_path:
             seg = min(seg, checkpoint_every)
-        out = wave_runner(key, state.run_idx, carry, seg)
-        waves = int(out.waves_done)  # the segment's single host sync
-        tp = time.time()
-        wave_runner.harvest(out, state)
-        postproc_s += time.time() - tp
+        with _phase(phases, "wave_loop"):
+            out = wave_runner(key, state.run_idx, carry, seg)
+            waves = int(out.waves_done)  # the segment's single host sync
+        with _phase(phases, "harvest",
+                    sample_days=waves * shard_batch * cfg.num_days):
+            wave_runner.harvest(out, state)
         carry = wave_runner.carry_of(out)
         state.run_idx += waves
         state.simulations += waves * cfg.batch_size
@@ -964,18 +975,26 @@ def _run_abc_device(
             state.save(checkpoint_path)
         if waves == 0:  # budget/target already consumed; avoid a spin
             break
+    return _posterior(cfg, spec, state, phases, t0)
 
-    theta, dist = state.to_arrays()
-    post = Posterior(
-        theta=theta,
-        distances=dist,
-        tolerance=cfg.tolerance,
-        param_names=run_param_names(cfg, spec),
-        runs=state.run_idx,
-        simulations=state.simulations,
-        wall_time_s=time.time() - t0,
-    )
-    post.postproc_time_s = postproc_s  # type: ignore[attr-defined]
+
+def _posterior(cfg: ABCConfig, spec, state: ABCState, phases: dict,
+               t0: float) -> Posterior:
+    """Every harvested sample as a Posterior (a run may overshoot
+    target_accepted; the paper keeps the overshoot too — callers can slice
+    with Posterior.top), with the driver's phases and wall time."""
+    with _phase(phases, "posterior"):
+        theta, dist = state.to_arrays()
+        post = Posterior(
+            theta=theta,
+            distances=dist,
+            tolerance=cfg.tolerance,
+            param_names=run_param_names(cfg, spec),
+            runs=state.run_idx,
+            simulations=state.simulations,
+            phase_s=phases,
+        )
+    post.wall_time_s = time.perf_counter() - t0
     return post
 
 

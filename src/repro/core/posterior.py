@@ -26,6 +26,9 @@ class Posterior:
     #: optional importance weights [N] (SMC populations); persisted so a
     #: stored posterior can warm-start a re-fit with its weighted population
     weights: Optional[np.ndarray] = None
+    #: host seconds of each phase of the wave driver (`init`, `wave_loop`,
+    #: `harvest`, `posterior`), summed over the run's segments; not saved
+    phase_s: Dict[str, float] = dataclasses.field(default_factory=dict)
 
     def __post_init__(self):
         self.theta = np.asarray(self.theta, np.float32).reshape(

@@ -8,7 +8,9 @@ backends, for every registered model.
 """
 
 import dataclasses
+import glob
 import os
+import re
 import tempfile
 
 import jax
@@ -286,3 +288,111 @@ def test_shardmap_wave_runner_matches_host_distributed_stream():
     np.testing.assert_array_equal(
         np.sort(p_host.distances), np.sort(p_dev.distances)
     )
+
+
+# ------------------------------------------------------------------------
+# Instrumentation: device scopes in the wave loop, host spans in the driver
+# ------------------------------------------------------------------------
+
+WAVE_SCOPES = ("abc.prior", "abc.simulate", "abc.accept")
+
+
+def _scopes_in(hlo_text: str) -> set:
+    """Named scopes of WAVE_SCOPES that some op_name of the text holds."""
+    return {part for name in re.findall(r'op_name="([^"]*)"', hlo_text)
+            for part in name.split("/") if part in WAVE_SCOPES}
+
+
+def _compiled_loop_text(runner) -> str:
+    th, d, n0, fill0 = runner.init(ABCState(n_params=runner.n_params))
+    return runner.fn.lower(
+        jax.random.PRNGKey(0), np.int32(0), th, d, n0, fill0, np.int32(2),
+        np.float32(runner.cfg.tolerance), runner.data,
+    ).compile().as_text()
+
+
+@pytest.mark.parametrize("backend", ["xla_fused", "pallas"])
+def test_compiled_wave_loop_carries_step_scopes(backend):
+    ds = get_dataset("synthetic_small", num_days=DAYS)
+    cfg = _cfg("siard", backend, 1e6, batch_size=1024, chunk_size=1024)
+    runner = make_wave_runner(get_model("siard").prior(),
+                              make_simulator(ds, cfg), cfg)
+    assert _scopes_in(_compiled_loop_text(runner)) == set(WAVE_SCOPES)
+
+
+def test_sharded_wave_loop_carries_step_scopes():
+    from conftest import run_in_subprocess
+
+    code = f"""
+import re, jax, numpy as np
+from repro.core import distributed
+from repro.core.abc import ABCConfig, ABCState
+from repro.core.scaling import device_mesh
+from repro.epi.data import get_dataset
+
+assert len(jax.devices()) == 4
+ds = get_dataset("synthetic_small", num_days={DAYS})
+cfg = ABCConfig(batch_size=1024, chunk_size=256, num_days={DAYS},
+                tolerance=1e6, target_accepted=20, max_runs=4)
+wr = distributed.make_wave_runner(device_mesh(4), ds, cfg, style="shard_map")
+th, d, n0, fills = wr.init(ABCState(n_params=wr.n_params))
+text = wr.fn.lower(jax.random.PRNGKey(0), np.int32(0), th, d, n0, fills,
+                   np.int32(2), np.float32(cfg.tolerance), None
+                   ).compile().as_text()
+names = re.findall(r'op_name="([^"]*)"', text)
+print(sorted({{p for n in names for p in n.split("/") if p.startswith("abc.")}}))
+"""
+    out = run_in_subprocess(code, n_devices=4)
+    assert out.strip().splitlines()[-1] == str(sorted(WAVE_SCOPES))
+
+
+def _host_spans(trace_dir) -> list:
+    """(name, start_ns, end_ns, stats) of the host spans of a trace."""
+    from jax.profiler import ProfileData
+
+    path, = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    return sorted(
+        [(e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+         for plane in ProfileData.from_file(path).planes
+         if plane.name.startswith("/host:CPU")
+         for line in plane.lines for e in line.events
+         if e.name == "fit" or e.name.startswith("abc.")],
+        key=lambda s: (s[1], -s[2]))
+
+
+def test_fit_spans_nest_in_order_with_counters(tmp_path):
+    ds = get_dataset("synthetic_small", num_days=DAYS)
+    cfg = _cfg("siard", "xla_fused", _model_tolerance("siard"))
+    runner = make_wave_runner(get_model("siard").prior(),
+                              make_simulator(ds, cfg), cfg)
+    run_abc(ds, cfg, key=0, wave_runner=runner)  # compile outside the trace
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with jax.profiler.TraceAnnotation("fit"):
+            post = run_abc(ds, cfg, key=1, wave_runner=runner)
+    finally:
+        jax.profiler.stop_trace()
+    spans = _host_spans(str(tmp_path))
+    assert [s[0] for s in spans] == ["fit", "abc.init", "abc.wave_loop",
+                                     "abc.harvest", "abc.posterior"]
+    fit, init, loop, harvest, posterior = spans
+    assert all(fit[1] <= s[1] and s[2] <= fit[2] for s in spans[1:])
+    assert all(a[2] <= b[1] for a, b in zip(spans[1:], spans[2:]))
+    assert init[3] == loop[3] == posterior[3] == {}
+    assert harvest[3] == {
+        "sample_days": post.runs * cfg.batch_size * cfg.num_days}
+
+
+@pytest.mark.parametrize("wave_loop,phases", [
+    ("device", {"init", "wave_loop", "harvest", "posterior"}),
+    ("host", {"wave_loop", "harvest", "posterior"}),
+])
+def test_phase_record_within_wall_time(wave_loop, phases):
+    ds = get_dataset("synthetic_small", num_days=DAYS)
+    cfg = _cfg("siard", "xla_fused", _model_tolerance("siard"),
+               wave_loop=wave_loop)
+    post = run_abc(ds, cfg, key=0)
+    assert set(post.phase_s) == phases
+    assert all(v > 0 for v in post.phase_s.values())
+    assert sum(post.phase_s.values()) <= post.wall_time_s

@@ -473,24 +473,101 @@ def wave_capacity(cfg: ABCConfig, batch_size: Optional[int] = None) -> int:
 
     The loop only enters a wave while accepted < target, and a wave adds at
     most one batch, so `target + batch - 1` bounds the fill — the final
-    wave's overshoot is retained exactly like the host outfeed path.
+    wave's overshoot is retained exactly like the host outfeed path. A
+    typical wave keeps far fewer rows than a batch (`compact_accepted`
+    writes those as one small window), but a wave that accepts more than
+    `COMPACT_ROWS` takes the full scatter, which may write a whole batch:
+    that is what the room is for.
     """
     return cfg.target_accepted + (batch_size or cfg.batch_size)
 
 
-def compact_accepted(th_buf, d_buf, fill, theta, dist, accept, capacity: int):
-    """Scatter accepted rows into the buffer's next free slots.
+#: rows a wave's bounded compaction writes, one lane tile: a wave that
+#: accepts more, or whose window would pass the buffer's end, falls back to
+#: the full scatter
+COMPACT_ROWS = 128
+#: accept flags are counted in chunks of this many rows to find the chunks
+#: that hold the first accepted rows
+_CHUNK = 128
 
-    Fixed shapes throughout: rejected rows get an out-of-bounds slot and are
-    dropped by the scatter. Returns (th_buf, d_buf, new_fill). Shared by the
-    ABC wave loop and the SMC device round — the capacity-edge semantics
-    exist exactly once.
+
+def _first_accepted(accept, k: int):
+    """Indices of the first `k` accepted rows, in ascending order.
+
+    Entries past the wave's count hold an arbitrary row. Two levels of
+    counting, so nothing touches the whole wave but one reduction: the
+    chunk holding the r-th accepted row is the number of chunks whose
+    running count is at most r, and its row within that chunk is found
+    the same way in the chunk's own flags.
     """
-    slot = fill + jnp.cumsum(accept.astype(jnp.int32)) - 1
-    slot = jnp.where(accept, slot, capacity)
-    th_buf = th_buf.at[slot].set(theta, mode="drop")
-    d_buf = d_buf.at[slot].set(dist, mode="drop")
-    return th_buf, d_buf, fill + jnp.sum(accept, dtype=jnp.int32)
+    b = accept.shape[0]
+    n_chunks = -(-b // _CHUNK)
+    flags = jnp.pad(accept.astype(jnp.int32), (0, n_chunks * _CHUNK - b))
+    flags = flags.reshape(n_chunks, _CHUNK)
+    counts = flags.sum(axis=1)
+    ends = jnp.cumsum(counts)
+    r = jax.lax.iota(jnp.int32, k)
+    chunk = jnp.sum(ends[None, :] <= r[:, None], axis=1, dtype=jnp.int32)
+    chunk = jnp.minimum(chunk, n_chunks - 1)
+    rank = r - (ends[chunk] - counts[chunk])
+    running = jnp.cumsum(flags[chunk], axis=1)
+    row = jnp.sum(running <= rank[:, None], axis=1, dtype=jnp.int32)
+    return jnp.minimum(chunk * _CHUNK + row, b - 1)
+
+
+def compact_accepted(th_buf, d_buf, fill, theta, dist, accept, capacity: int):
+    """Write accepted rows, in stream order, into the buffer's next free
+    slots. Returns (th_buf, d_buf, new_fill).
+
+    A wave that accepts at most `k = min(COMPACT_ROWS, batch, capacity)`
+    rows, and whose `k`-row window at `fill` lies inside the buffer, writes
+    its first `k` accepted rows as that window, keeping the window's old
+    rows past its count. Every other wave, and every wave of a batch of at
+    most `COMPACT_ROWS`, takes the full scatter (under the named scope
+    `abc.accept_fallback`): rejected rows get an out-of-bounds slot and
+    are dropped. Only the scatter meets the capacity edge, so it alone
+    keeps that contract: a prefix fills the buffer exactly, the excess is
+    dropped, and `new_fill` counts every accept, so it may pass `capacity`
+    (callers clamp). Both branches give the same bits. Shared by the ABC
+    wave loop and the SMC device round — the capacity-edge semantics exist
+    exactly once.
+    """
+    b, p = theta.shape
+    k = min(COMPACT_ROWS, b, capacity)
+    count = jnp.sum(accept, dtype=jnp.int32)
+
+    def scatter(th_buf, d_buf):
+        slot = fill + jnp.cumsum(accept.astype(jnp.int32)) - 1
+        slot = jnp.where(accept, slot, capacity)
+        th_buf = th_buf.at[slot].set(theta, mode="drop")
+        d_buf = d_buf.at[slot].set(dist, mode="drop")
+        return th_buf, d_buf
+
+    if b <= COMPACT_ROWS:
+        return (*scatter(th_buf, d_buf), fill + count)
+
+    def bounded(th_buf, d_buf):
+        idx = _first_accepted(accept, k)
+        new = jax.lax.iota(jnp.int32, k) < count
+        # a column at a time: a gather of whole rows has XLA lay theta and
+        # the buffer out with their p columns padded to a lane tile
+        rows = jnp.stack([theta[:, j][idx] for j in range(p)], axis=1)
+        old_th = jax.lax.dynamic_slice(th_buf, (fill, 0), (k, p))
+        old_d = jax.lax.dynamic_slice(d_buf, (fill,), (k,))
+        th_buf = jax.lax.dynamic_update_slice(
+            th_buf, jnp.where(new[:, None], rows, old_th), (fill, 0))
+        d_buf = jax.lax.dynamic_update_slice(
+            d_buf, jnp.where(new, dist[idx], old_d), (fill,))
+        return th_buf, d_buf
+
+    def fallback(th_buf, d_buf):
+        with jax.named_scope("abc.accept_fallback"):
+            return scatter(th_buf, d_buf)
+
+    th_buf, d_buf = jax.lax.cond(
+        (count <= k) & (fill <= capacity - k), bounded, fallback,
+        th_buf, d_buf)
+    return th_buf, d_buf, fill + count
 
 
 def wave_loop_body(
@@ -505,7 +582,8 @@ def wave_loop_body(
     """One wave: sample -> simulate -> compare -> compact into the buffer.
 
     The three steps run under the named scopes `abc.prior`, `abc.simulate`
-    and `abc.accept`, which XLA keeps in each operation's `op_name`
+    and `abc.accept` (with the compaction's full scatter nested in it as
+    `abc.accept_fallback`), which XLA keeps in each operation's `op_name`
     metadata, so a device trace can tell them apart whichever simulator
     backend runs; the key derivation and the stop count stay outside.
 

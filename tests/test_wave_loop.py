@@ -19,8 +19,11 @@ import numpy as np
 import pytest
 
 from repro.core.abc import (
+    COMPACT_ROWS as K,
     ABCConfig,
     ABCState,
+    build_wave_loop,
+    compact_accepted,
     make_simulator,
     make_wave_runner,
     run_abc,
@@ -223,6 +226,80 @@ def test_compact_accepted_overflow_drops_excess_keeps_prefix():
     assert min(int(fill2), cap) == cap
 
 
+def _plain_scatter(th_buf, d_buf, fill, theta, dist, accept, capacity):
+    """The accept-buffer contract, row by row: accepted rows in stream
+    order from `fill` on, those past `capacity` dropped, every accept
+    counted."""
+    th, d = np.array(th_buf), np.array(d_buf)
+    for rank, i in enumerate(np.flatnonzero(accept)):
+        if fill + rank < capacity:
+            th[fill + rank], d[fill + rank] = theta[i], dist[i]
+    return th, d, fill + int(np.sum(accept))
+
+
+#: (id, batch, p, accepts, fill) into a buffer of _CAP rows, on either
+#: side of the count and capacity edges that choose the branch; the batch
+#: of 300 is not a multiple of the 128-row chunks
+_CAP = 320
+_CASES = (
+    [(f"accepts={n}", 300, 8, n, 5) for n in (0, 1, K - 1, K, K + 1, 300)]
+    + [(f"fill+K=cap{d:+d}", 300, 2, 3, _CAP - K + d) for d in (-1, 0, 1)]
+    + [("p=2,accepts=K", 300, 2, K, 5),
+       ("fill>cap", 300, 8, 3, _CAP + 7),
+       ("batch<K", 64, 8, 10, 5),
+       ("batch<K,overflow", 64, 8, 40, _CAP - 20)])
+
+
+@pytest.mark.parametrize("B,p,n_accept,fill", [c[1:] for c in _CASES],
+                         ids=[c[0] for c in _CASES])
+def test_compact_accepted_matches_a_plain_scatter(B, p, n_accept, fill):
+    """Bounded window or full scatter, the buffers and fill are bitwise
+    those of the contract."""
+    rng = np.random.default_rng(n_accept * 1009 + fill)
+    accept = np.zeros(B, bool)
+    accept[rng.choice(B, n_accept, replace=False)] = True
+    theta = rng.standard_normal((B, p)).astype(np.float32)
+    dist = rng.standard_normal(B).astype(np.float32)
+    th_buf = rng.standard_normal((_CAP, p)).astype(np.float32)
+    d_buf = rng.standard_normal(_CAP).astype(np.float32)
+    got = jax.jit(compact_accepted, static_argnums=6)(
+        th_buf, d_buf, jnp.int32(fill), theta, dist, accept, _CAP)
+    want = _plain_scatter(th_buf, d_buf, fill, theta, dist, accept, _CAP)
+    assert int(got[2]) == want[2]
+    for g, w in zip(got[:2], want[:2]):
+        np.testing.assert_array_equal(np.asarray(g).view(np.uint32),
+                                      w.view(np.uint32))
+
+
+def test_wave_loop_one_wave_over_the_bound_falls_back(small_dataset):
+    """A wave of COMPACT_ROWS + 1 accepts into a COMPACT_ROWS-row buffer
+    takes the full scatter: the first COMPACT_ROWS accepted rows land in
+    stream order, fill_counts is clamped and n_accepted counts them all."""
+    B = 2 * K + 44
+    cfg = ABCConfig(batch_size=B, target_accepted=10**6, chunk_size=B,
+                    num_days=15, max_runs=2)
+    prior = get_model("siard").prior()
+    # a permutation of 0..B-1 as the distance: rows at most K are accepted
+    perm = (np.arange(B) * 7919) % B
+    loop = jax.jit(build_wave_loop(
+        prior, lambda th, k, _d: jnp.asarray(perm, jnp.float32), cfg,
+        capacity=K))
+    th0 = jnp.zeros((K, prior.dim), jnp.float32)
+    d0 = jnp.full((K,), jnp.inf, jnp.float32)
+    key = jax.random.PRNGKey(3)
+    out = loop(key, 0, th0, d0, 0, 0, 1, np.float32(K), None)
+    assert int(out.waves_done) == 1
+    assert int(out.n_accepted) == K + 1
+    assert int(out.fill_counts[0]) == K
+    # wave 0 draws theta from the first half of fold_in(key, 0)
+    theta = prior.sample(jax.random.split(jax.random.fold_in(key, 0))[0],
+                         (B,))
+    rows = np.flatnonzero(perm <= K)[:K]
+    np.testing.assert_array_equal(np.asarray(out.dist_buf), perm[rows])
+    np.testing.assert_array_equal(np.asarray(out.theta_buf),
+                                  np.asarray(theta)[rows])
+
+
 def test_wave_loop_single_wave_overflow_reports_clamped_fill(small_dataset):
     """A capacity-capped loop whose single wave over-accepts must clamp
     fill_counts to capacity while n_accepted counts every acceptance."""
@@ -343,7 +420,9 @@ names = re.findall(r'op_name="([^"]*)"', text)
 print(sorted({{p for n in names for p in n.split("/") if p.startswith("abc.")}}))
 """
     out = run_in_subprocess(code, n_devices=4)
-    assert out.strip().splitlines()[-1] == str(sorted(WAVE_SCOPES))
+    # 256 rows a chip: the compaction's full-scatter branch is in the loop
+    assert out.strip().splitlines()[-1] == str(
+        sorted(WAVE_SCOPES + ("abc.accept_fallback",)))
 
 
 def _host_spans(trace_dir) -> list:

@@ -133,8 +133,9 @@ class ABCConfig:
     #: explicitly set tile/scan_unroll values always win over the cache
     autotune: bool = False
     #: metapop models only: a row-stochastic [R, R] mobility matrix (nested
-    #: tuples) overriding the spec's static one — validated loudly here (rows
-    #: must sum to 1); None keeps the model's own matrix. The matrix is a
+    #: tuples) overriding the dataset's and the spec's — validated loudly
+    #: here (rows must sum to 1); None keeps the dataset's geography matrix
+    #: where it has one, else the model's own. The matrix is a
     #: RUNTIME value on every backend (fconst lanes on pallas), so mobility
     #: sweeps share one compilation.
     mobility: Optional[Tuple[Tuple[float, ...], ...]] = None
@@ -219,21 +220,27 @@ def run_param_names(cfg: ABCConfig, spec) -> Tuple[str, ...]:
 SimulatorFn = Callable[[Array, Array], Array]  # (theta [B,p], key) -> dist [B]
 
 
-def resolved_mobility(cfg: ABCConfig, spec) -> Optional[Array]:
-    """cfg.mobility as an [R, R] f32 array, checked against the spec's
-    region count; None defers to the spec's own (validated) matrix."""
-    if cfg.mobility is None:
+def resolved_mobility(cfg: Optional[ABCConfig], spec,
+                      dataset: Optional[CountryData] = None) -> Optional[Array]:
+    """The run's mobility as an [R, R] f32 array: cfg.mobility, else the
+    geography matrix of `dataset`, checked against the spec's region count;
+    None defers to the spec's own (validated) matrix. The one place that
+    settles the precedence; `cfg` None stands for a config without one."""
+    mob, whose = getattr(cfg, "mobility", None), "cfg.mobility"
+    if mob is None and dataset is not None:
+        mob, whose = dataset.mobility, f"dataset {dataset.name!r} mobility"
+    if mob is None:
         return None
     if not spec.is_regional:
         raise ValueError(
-            f"cfg.mobility set but model {spec.name!r} has no region axis"
+            f"{whose} set but model {spec.name!r} has no region axis"
         )
-    if len(cfg.mobility) != spec.n_regions:
+    if len(mob) != spec.n_regions:
         raise ValueError(
-            f"cfg.mobility is {len(cfg.mobility)}x{len(cfg.mobility)} but "
+            f"{whose} is {len(mob)}x{len(mob)} but "
             f"model {spec.name!r} has {spec.n_regions} regions"
         )
-    return jnp.asarray(cfg.mobility, jnp.float32)
+    return jnp.asarray(mob, jnp.float32)
 
 
 class ScenarioData(NamedTuple):
@@ -245,24 +252,33 @@ class ScenarioData(NamedTuple):
     intervention fields make lockdown-day x scale campaign grids share one
     compilation: breakpoint days are an i32 vector, and the (possibly
     pinned) per-window scale bounds ride in the prior box arrays.
+
+    A dataset with a geography carries it here too: population and day-0
+    counts as [R] rows, all traced, so datasets of one shape share a
+    compile. Without one the four are f32 scalars. `mobility` is the run's
+    matrix (`resolved_mobility`: cfg.mobility, else the dataset's), traced;
+    None (no leaf) leaves the spec's, the program of before geographies.
     """
 
     observed: Array  # [n_obs, T] f32
-    population: Array  # f32 scalar
-    a0: Array  # f32 scalar
-    r0: Array  # f32 scalar
-    d0: Array  # f32 scalar
+    population: Array  # f32 scalar total, or [R] f32 with a geography
+    a0: Array  # f32 scalar, or [R] f32 with a geography
+    r0: Array  # f32 scalar, or [R] f32 with a geography
+    d0: Array  # f32 scalar, or [R] f32 with a geography
     breakpoints: Array  # [n_windows] i32 (length 0 without a schedule)
     prior_lows: Array  # [p_total] f32 — the (widened) sampling box
     prior_highs: Array  # [p_total] f32
+    #: the run's [R, R] f32 mobility; None: the spec's own
+    mobility: Optional[Array] = None
 
 
 def make_parametric_simulator(spec, cfg: ABCConfig):
     """theta -> distance with the *dataset as traced arguments*.
 
     Returns `sim(theta [B,p], key, data: ScenarioData) -> dist [B]`. Because
-    the observed series, the (population, a0, r0, d0) scalars and any
-    intervention breakpoint days are inputs rather than baked-in constants,
+    the observed series, the (population, a0, r0, d0) scalars (or a
+    geography's rows and mobility) and any intervention breakpoint days are
+    inputs rather than baked-in constants,
     one jitted computation serves every dataset/scenario of the same
     (model, num_days, batch, schedule-shape) — the campaign runner relies on
     this to compile once per shape and sweep countries/seeds/interventions.
@@ -284,7 +300,6 @@ def make_parametric_simulator(spec, cfg: ABCConfig):
         )
     schedule = cfg.schedule
     summary = cfg.summary_spec
-    mob = resolved_mobility(cfg, spec)
     pool = pool_factor(summary, spec.n_regions)
     # identity summaries keep the legacy full-trajectory distance functions
     # (bit-compat for all three registered distances); a real summary lowers
@@ -293,13 +308,16 @@ def make_parametric_simulator(spec, cfg: ABCConfig):
 
     def simulator(theta: Array, key: Array, data: ScenarioData) -> Array:
         observed, population, a0, r0, d0 = data[:5]
-        breakpoints = data.breakpoints if isinstance(data, ScenarioData) else None
+        breakpoints = mobility = None
+        if isinstance(data, ScenarioData):
+            breakpoints, mobility = data.breakpoints, data.mobility
         mcfg = EpiModelConfig(
             population=population, num_days=cfg.num_days, a0=a0, r0=r0, d0=d0
         )
         if cfg.backend == "xla":
             sim = engine.simulate_observed(
-                spec, theta, key, mcfg, schedule, breakpoints, mobility=mob
+                spec, theta, key, mcfg, schedule, breakpoints,
+                mobility=mobility,
             )
             if dist_fn is not None:
                 return dist_fn(sim, observed)
@@ -313,7 +331,7 @@ def make_parametric_simulator(spec, cfg: ABCConfig):
         d, _ = engine.simulate_observed_lowmem(
             spec, theta, key, mcfg, observed, schedule, breakpoints,
             summary=summary, distance=cfg.distance,
-            unroll=cfg.scan_unroll or 1, mobility=mob,
+            unroll=cfg.scan_unroll or 1, mobility=mobility,
         )
         return d
 
@@ -323,8 +341,11 @@ def make_parametric_simulator(spec, cfg: ABCConfig):
 def scenario_data(
     dataset: CountryData, cfg: ABCConfig, prior: Optional[UniformBoxPrior] = None
 ) -> ScenarioData:
-    """Pack a dataset into the traced-argument tuple of a parametric simulator."""
-    prior = prior or schedule_prior(get_model(cfg.model), cfg.schedule)
+    """Pack a dataset into the traced-argument tuple of a parametric
+    simulator: a geography's population and day-0 rows, and the run's
+    mobility (`resolved_mobility`) too."""
+    spec = get_model(cfg.model)
+    prior = prior or schedule_prior(spec, cfg.schedule)
     breakpoints = (
         cfg.schedule.breakpoints if cfg.schedule is not None else ()
     )
@@ -337,6 +358,7 @@ def scenario_data(
         breakpoints=jnp.asarray(breakpoints, jnp.int32),
         prior_lows=jnp.asarray(prior.lows, jnp.float32),
         prior_highs=jnp.asarray(prior.highs, jnp.float32),
+        mobility=resolved_mobility(cfg, spec, dataset),
     )
 
 
@@ -347,6 +369,9 @@ def make_simulator(dataset: CountryData, cfg: ABCConfig) -> SimulatorFn:
     the same observed channels (checked here, not at run time). With
     `cfg.schedule`, theta must carry the widened scale columns
     (`schedule_prior(spec, cfg.schedule)` samples the right layout).
+    A dataset's geography (per-region populations and seeds, its mobility)
+    rides in its `ScenarioData`; mobility is cfg.mobility, else the
+    dataset's, else the spec's. The "pallas" backend refuses a geography.
     """
     if cfg.backend == "npe":
         raise ValueError(
@@ -379,6 +404,13 @@ def make_simulator(dataset: CountryData, cfg: ABCConfig) -> SimulatorFn:
     else:  # pallas
         from repro.kernels import ops as kernel_ops
 
+        if dataset.regions is not None:
+            raise ValueError(
+                f"backend='pallas' bakes scalar populations and day-0 counts "
+                f"into the kernel; dataset {dataset.name!r} carries a "
+                f"{dataset.regions}-region geography: use backend='xla_fused' "
+                "or 'xla'"
+            )
         mob = resolved_mobility(cfg, spec)
 
         def simulator(theta: Array, key: Array) -> Array:
@@ -885,6 +917,15 @@ def _phase(phases: dict, name: str, **counters):
     phases[name] = phases.get(name, 0.0) + time.perf_counter() - t0
 
 
+def _day_counters(spec, sample_days: int) -> dict:
+    """The `abc.harvest` span's counters: `sample_days` (waves x batch per
+    chip x days), and for a regional spec `region_days`, that times R."""
+    if not spec.is_regional:
+        return {"sample_days": sample_days}
+    return {"sample_days": sample_days,
+            "region_days": sample_days * spec.n_regions}
+
+
 def _harvest(out: RunOutput, cfg: ABCConfig, state: ABCState) -> int:
     """Host-side postprocessing of one run's outputs (paper §3.2 / Table 4).
 
@@ -990,8 +1031,8 @@ def run_abc(
         with _phase(phases, "wave_loop"):
             out = run_fn(run_key)
             out = jax.tree.map(jax.block_until_ready, out)
-        with _phase(phases, "harvest",
-                    sample_days=cfg.batch_size * cfg.num_days):
+        with _phase(phases, "harvest", **_day_counters(
+                spec, cfg.batch_size * cfg.num_days)):
             _harvest(out, cfg, state)
         state.run_idx += 1
         state.simulations += cfg.batch_size
@@ -1038,8 +1079,8 @@ def _run_abc_device(
         with _phase(phases, "wave_loop"):
             out = wave_runner(key, state.run_idx, carry, seg)
             waves = int(out.waves_done)  # the segment's single host sync
-        with _phase(phases, "harvest",
-                    sample_days=waves * shard_batch * cfg.num_days):
+        with _phase(phases, "harvest", **_day_counters(
+                spec, waves * shard_batch * cfg.num_days)):
             wave_runner.harvest(out, state)
         carry = wave_runner.carry_of(out)
         state.run_idx += waves

@@ -447,7 +447,7 @@ def _train_setup(dataset: CountryData, cfg, prior: Optional[UniformBoxPrior]):
         )
     prior = prior or schedule_prior(spec, cfg.schedule)
     mcfg = dataset.model_config(cfg.num_days)
-    mob = resolved_mobility(cfg, spec)
+    mob = resolved_mobility(cfg, spec, dataset)
     return spec, prior, mcfg, mob, cfg.summary_spec, resolve_npe_config(cfg.npe)
 
 
@@ -605,12 +605,16 @@ def fine_tune(
         )
     prior = UniformBoxPrior(highs=tuple(est.highs), lows=tuple(est.lows))
     mcfg = dataset.model_config(est.num_days)
+    from repro.core.abc import resolved_mobility
+
+    # a geography's matrix, as in training on this dataset
+    mob = resolved_mobility(None, spec, dataset)
     opt_cfg = AdamWConfig(
         lr=est.npe.fine_tune_lr, weight_decay=est.npe.weight_decay,
         warmup_steps=1, total_steps=max(steps, 1),
     )
     step_fn = _make_train_step(
-        spec, prior, mcfg, est.schedule, est.summary, None, est.npe, opt_cfg,
+        spec, prior, mcfg, est.schedule, est.summary, mob, est.npe, opt_cfg,
         est.lows, est.highs, est.feat_mean, est.feat_std,
     )
     params, opt_state, loss = est.params, adamw_init(est.params), None

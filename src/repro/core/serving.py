@@ -214,6 +214,18 @@ def bands_payload(
     return _jsonable(payload)
 
 
+def refuse_geography(ds: CountryData) -> CountryData:
+    """The forecast kernels take scalar populations and the spec's mobility:
+    a dataset with a geography is refused rather than forecast with another
+    model than the one it was fitted with."""
+    if ds.regions is not None:
+        raise ValueError(
+            f"dataset {ds.name!r} carries a {ds.regions}-region geography; "
+            "the serving layer forecasts only datasets without one"
+        )
+    return ds
+
+
 def forecast_bands(
     theta,
     dataset: CountryData,
@@ -235,6 +247,7 @@ def forecast_bands(
     share every step (seeded subsample, schedule widening, traced core,
     payload assembly)."""
     spec = get_model(model)
+    refuse_geography(dataset)
     counterfactual = schedule is not None
     fc_sched = schedule if counterfactual else fit_schedule
     theta = np.asarray(theta, np.float32)
@@ -582,7 +595,8 @@ class EpiServer:
                         ds, observed=ds.observed[:, :fit_days]
                     )
                 return ds, dataset_version(ds)
-        ds = get_dataset(name, num_days=fit_days, model=model)
+        ds = refuse_geography(
+            get_dataset(name, num_days=fit_days, model=model))
         return ds, dataset_version(ds)
 
     # -- posteriors --------------------------------------------------------

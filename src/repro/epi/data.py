@@ -15,8 +15,17 @@ cases. This container is offline, so we provide:
     series (the paper model), but any model whose observed channels are
     (A, R, D) — e.g. seiard — can be fitted against them.
 
+  * `usa_states` — a 51-region metapop_seir series over the 50 US states
+    and the District of Columbia: per-state 2020 Census populations, the
+    national `usa` day-0 counts split over the states by population, and a
+    gravity mobility matrix between the state capitals (`gravity_mobility`).
+    A generated stand-in for the JHU CSSE state-level feed, like the
+    national series.
+
 Every dataset is a `CountryData` with observed [n_observed, T] series; the
 `model` field names the spec whose observed channels the rows correspond to.
+A regional dataset may carry a geography: length-R populations and day-0
+counts, and a row-stochastic [R][R] mobility matrix.
 """
 
 from __future__ import annotations
@@ -29,16 +38,27 @@ import numpy as np
 
 from repro.epi import engine
 from repro.epi.models import get_model
-from repro.epi.spec import CompartmentalModel, EpiModelConfig
+from repro.epi.spec import (
+    CompartmentalModel,
+    EpiModelConfig,
+    regionalize,
+    validate_mobility,
+)
+
+#: a scalar, or a geography's per-region values (length R)
+RegionValue = Union[float, Tuple[float, ...]]
 
 
 @dataclasses.dataclass(frozen=True)
 class CountryData:
     name: str
-    population: float
-    a0: float
-    r0: float
-    d0: float
+    #: total population, or a geography's length-R per-region populations
+    population: RegionValue
+    #: day-0 counts: scalars that seed the spec's `seed_region`, or a
+    #: geography's length-R per-region counts
+    a0: RegionValue
+    r0: RegionValue
+    d0: RegionValue
     observed: np.ndarray  # [n_observed, T] float32 — per-day observed channels
     #: tolerance the paper used for this dataset (Table 8), where applicable
     paper_tolerance: float | None = None
@@ -52,6 +72,36 @@ class CountryData:
     #: unregistered or since-replaced specs). Metapop datasets carry the
     #: region-major flattened labels ("I@r0", "R@r0", "I@r1", ...).
     observed_channels: Tuple[str, ...] = ("A", "R", "D")
+    #: a geography's row-stochastic [R][R] mobility matrix, which replaces
+    #: the spec's own unless the run's `ABCConfig.mobility` is set
+    mobility: Tuple[Tuple[float, ...], ...] | None = None
+
+    def __post_init__(self):
+        per_region = [f for f in ("population", "a0", "r0", "d0")
+                      if np.ndim(getattr(self, f))]
+        sizes = {len(getattr(self, f)) for f in per_region}
+        if self.mobility is not None:
+            sizes.add(len(self.mobility))
+        if len(sizes) > 1:
+            raise ValueError(
+                f"dataset {self.name!r}: the geography's per-region values "
+                f"and mobility disagree on the region count ({sorted(sizes)})"
+            )
+        for f in per_region:
+            object.__setattr__(
+                self, f, tuple(float(x) for x in getattr(self, f)))
+        if self.mobility is not None:
+            object.__setattr__(self, "mobility",
+                               validate_mobility(self.mobility, sizes.pop()))
+
+    @property
+    def regions(self) -> int | None:
+        """Region count of the dataset's geography; None without one."""
+        for value in (self.mobility, self.population, self.a0, self.r0,
+                      self.d0):
+            if np.ndim(value):
+                return len(value)
+        return None
 
     @property
     def num_days(self) -> int:
@@ -69,22 +119,25 @@ class CountryData:
     def compatible_with(self, spec: CompartmentalModel) -> bool:
         """A spec can fit this dataset iff its observed channels line up
         (for metapop specs: the flattened per-region labels, so region
-        count mismatches are caught too)."""
-        return spec.observed_labels == self.observed_channels
+        count mismatches are caught too), and a geography has as many
+        regions as the spec."""
+        return (spec.observed_labels == self.observed_channels
+                and self.regions in (None, spec.n_regions))
 
 
 def synthetic_dataset(
     theta: Tuple[float, ...],
-    population: float,
+    population: RegionValue,
     num_days: int = 49,
-    a0: float = 100.0,
-    r0: float = 0.0,
-    d0: float = 0.0,
+    a0: RegionValue = 100.0,
+    r0: RegionValue = 0.0,
+    d0: RegionValue = 0.0,
     seed: int = 0,
     name: str = "synthetic",
     paper_tolerance: float | None = None,
     model: Union[str, CompartmentalModel] = "siard",
     schedule=None,
+    mobility=None,
 ) -> CountryData:
     """Generate a ground-truth dataset by simulating with known parameters.
 
@@ -93,6 +146,9 @@ def synthetic_dataset(
     — which is the validation target for intervention-aware inference.
     `theta` is the base parameter vector; the schedule's pinned scales are
     appended automatically (pass a full widened theta to override).
+
+    For a metapop `model`, length-R `population` / day-0 counts and a
+    `mobility` matrix make a dataset with a geography, simulated with them.
     """
     spec = get_model(model)
     cfg = EpiModelConfig(
@@ -113,8 +169,10 @@ def synthetic_dataset(
             f"theta has {th.shape[1]} entries; model {spec.name!r} "
             f"expects {width}"
         )
+    if mobility is not None:
+        mobility = validate_mobility(mobility, spec.n_regions)
     obs = engine.simulate_observed(
-        spec, th, jax.random.PRNGKey(seed), cfg, schedule
+        spec, th, jax.random.PRNGKey(seed), cfg, schedule, mobility=mobility
     )[0]
     return CountryData(
         name=name,
@@ -128,6 +186,7 @@ def synthetic_dataset(
         synthetic=True,
         model=spec.name,
         observed_channels=spec.observed_labels,
+        mobility=mobility,
     )
 
 
@@ -151,11 +210,122 @@ _COUNTRY_META = {
 #: other models use their spec's default_theta.
 _SYNTH_SMALL_THETA = {"siard": (0.4, 30.0, 0.8, 0.05, 0.3, 0.01, 0.5, 1.0)}
 
+#: the 50 US states and the District of Columbia in FIPS order: postal
+#: code, resident population (US Census Bureau, 2020 Census Apportionment
+#: Results, April 2021, Table 2; the sum is the published 331,449,281) and
+#: the capital's latitude and longitude in degrees (approximate, to 0.01)
+US_STATES = (
+    ("AL", 5024279, 32.38, -86.30), ("AK", 733391, 58.30, -134.42),
+    ("AZ", 7151502, 33.45, -112.07), ("AR", 3011524, 34.75, -92.29),
+    ("CA", 39538223, 38.58, -121.49), ("CO", 5773714, 39.74, -104.99),
+    ("CT", 3605944, 41.76, -72.68), ("DE", 989948, 39.16, -75.52),
+    ("DC", 689545, 38.91, -77.04), ("FL", 21538187, 30.44, -84.28),
+    ("GA", 10711908, 33.75, -84.39), ("HI", 1455271, 21.31, -157.86),
+    ("ID", 1839106, 43.62, -116.20), ("IL", 12812508, 39.78, -89.65),
+    ("IN", 6785528, 39.77, -86.16), ("IA", 3190369, 41.59, -93.62),
+    ("KS", 2937880, 39.05, -95.68), ("KY", 4505836, 38.20, -84.87),
+    ("LA", 4657757, 30.45, -91.19), ("ME", 1362359, 44.31, -69.78),
+    ("MD", 6177224, 38.98, -76.49), ("MA", 7029917, 42.36, -71.06),
+    ("MI", 10077331, 42.73, -84.56), ("MN", 5706494, 44.95, -93.09),
+    ("MS", 2961279, 32.30, -90.18), ("MO", 6154913, 38.58, -92.17),
+    ("MT", 1084225, 46.59, -112.04), ("NE", 1961504, 40.81, -96.70),
+    ("NV", 3104614, 39.16, -119.77), ("NH", 1377529, 43.21, -71.54),
+    ("NJ", 9288994, 40.22, -74.76), ("NM", 2117522, 35.69, -105.94),
+    ("NY", 20201249, 42.65, -73.76), ("NC", 10439388, 35.78, -78.64),
+    ("ND", 779094, 46.81, -100.78), ("OH", 11799448, 39.96, -83.00),
+    ("OK", 3959353, 35.47, -97.52), ("OR", 4237256, 44.94, -123.04),
+    ("PA", 13002700, 40.27, -76.88), ("RI", 1097379, 41.82, -71.41),
+    ("SC", 5118425, 34.00, -81.03), ("SD", 886667, 44.37, -100.35),
+    ("TN", 6910840, 36.16, -86.78), ("TX", 29145505, 30.27, -97.74),
+    ("UT", 3271616, 40.76, -111.89), ("VT", 643077, 44.26, -72.58),
+    ("VA", 8631393, 37.54, -77.44), ("WA", 7705281, 47.04, -122.90),
+    ("WV", 1793716, 38.35, -81.63), ("WI", 5893718, 43.07, -89.40),
+    ("WY", 576851, 41.14, -104.82),
+)
+
+#: the `usa_states` stand-in: metapop_seir's default_theta, the national
+#: `usa` series' day-0 A0 and R0 split over the states, a gravity matrix
+#: with the classical exponents (b = 1, c = 2) and eps = 0.04, about the
+#: share of US workers who work outside their state of residence
+_USA_STATES_META = {"a0": 104, "r0": 7, "b": 1.0, "c": 2.0, "eps": 0.04,
+                    "seed": 51}
+
+#: mean Earth radius (km) of the haversine distance
+EARTH_RADIUS_KM = 6371.0
+
+
+def haversine_km(coords) -> np.ndarray:
+    """[R, R] great-circle distances (km, float64) between the (latitude,
+    longitude) points `coords`, in degrees."""
+    lat, lon = np.radians(np.asarray(coords, np.float64)).T
+    h = (np.sin((lat[:, None] - lat[None, :]) / 2.0) ** 2
+         + np.cos(lat[:, None]) * np.cos(lat[None, :])
+         * np.sin((lon[:, None] - lon[None, :]) / 2.0) ** 2)
+    return 2.0 * EARTH_RADIUS_KM * np.arcsin(np.sqrt(h))
+
+
+def gravity_mobility(populations, coords, b: float = 1.0, c: float = 2.0,
+                     eps: float = 0.04) -> Tuple[Tuple[float, ...], ...]:
+    """Row-stochastic gravity coupling between regions.
+
+    M[i][i] = 1 - eps; off the diagonal region i spreads eps over the others
+    in proportion to P_j^b / d_ij^c (P_i cancels in a row), d_ij the
+    haversine distance between the regions' points. Computed in float64 and
+    rounded once to float32."""
+    pop = np.asarray(populations, np.float64)
+    dist = haversine_km(coords)
+    if (dist[~np.eye(pop.size, dtype=bool)] <= 0.0).any():
+        raise ValueError("two regions share a point: gravity needs d_ij > 0")
+    np.fill_diagonal(dist, np.inf)  # no weight on a region's own row entry
+    weight = pop[None, :] ** b / dist ** c
+    m = eps * weight / weight.sum(axis=1, keepdims=True)
+    np.fill_diagonal(m, 1.0 - eps)
+    return validate_mobility(m.astype(np.float32).tolist(), pop.size)
+
+
+def split_by_population(total: int, populations) -> Tuple[float, ...]:
+    """`total` whole counts over regions in proportion to population, by
+    largest remainder (ties go to the earlier region)."""
+    pop = [int(p) for p in populations]
+    whole = sum(pop)
+    counts = [total * p // whole for p in pop]
+    remainders = [total * p % whole for p in pop]
+    order = sorted(range(len(pop)), key=lambda i: -remainders[i])
+    for i in order[: total - sum(counts)]:
+        counts[i] += 1
+    return tuple(float(x) for x in counts)
+
+
+def usa_states_model() -> CompartmentalModel:
+    """The spec `usa_states` is generated with: metapop_seir on 51 regions
+    (the dataset's own gravity matrix replaces the spec's identity)."""
+    return regionalize(get_model("metapop_seir"), len(US_STATES))
+
+
+def _usa_states(num_days: int, spec: CompartmentalModel) -> CountryData:
+    meta = _USA_STATES_META
+    pops = [p for _, p, _, _ in US_STATES]
+    return synthetic_dataset(
+        theta=spec.default_theta,
+        population=tuple(float(p) for p in pops),
+        num_days=num_days,
+        a0=split_by_population(meta["a0"], pops),
+        r0=split_by_population(meta["r0"], pops),
+        d0=0.0,
+        seed=meta["seed"],
+        name="usa_states",
+        model=spec,
+        mobility=gravity_mobility(
+            pops, [(lat, lon) for _, _, lat, lon in US_STATES],
+            meta["b"], meta["c"], meta["eps"]),
+    )
+
+
 _CACHE: Dict[tuple, CountryData] = {}
 
 
 def list_datasets() -> Tuple[str, ...]:
-    return tuple(sorted(_COUNTRY_META)) + ("synthetic_small",)
+    return tuple(sorted(_COUNTRY_META)) + ("synthetic_small", "usa_states")
 
 
 def list_countries() -> Tuple[str, ...]:
@@ -170,11 +340,13 @@ def get_dataset(
     model: Union[str, CompartmentalModel] = "siard",
 ) -> CountryData:
     """Fetch a bundled dataset by name ('italy' | 'new_zealand' | 'usa' |
-    'synthetic_small').
+    'synthetic_small' | 'usa_states').
 
     `model` selects which registry spec generates (and is fitted against)
     the series. The bundled country series are SIARD-generated; they can be
     requested for any model with matching observed channels (e.g. seiard).
+    `usa_states` is generated by `usa_states_model()` and fits any 51-region
+    spec that observes (I, R), such as `regionalize(metapop_seir, 51)`.
     """
     spec = get_model(model)
     # key on the spec object itself (hashable by design), not its name: two
@@ -195,6 +367,19 @@ def get_dataset(
             paper_tolerance=None,
             model=spec,
         )
+    elif name == "usa_states":
+        base_spec = usa_states_model()
+        if spec.observed_labels != base_spec.observed_labels:
+            raise ValueError(
+                f"dataset {name!r} holds (I, R) series of "
+                f"{base_spec.n_regions} regions; model {spec.name!r} "
+                f"observes {spec.n_regions} region(s) of {spec.observed}"
+            )
+        if spec == base_spec:
+            ds = _usa_states(num_days, spec)
+        else:
+            base = get_dataset(name, num_days=num_days, model=base_spec)
+            ds = dataclasses.replace(base, model=spec.name, true_theta=None)
     elif name in _COUNTRY_META:
         if spec.name != "siard":
             # the series stays SIARD-generated; re-tag the cached siard entry

@@ -34,7 +34,11 @@ model costs one contraction per day, not an unrolled R^2 expression. The
 flat R=1 uncoupled branch is untouched code, keeping every registered model
 bit-identical to pre-metapop releases (pinned by tests/test_metapop.py).
 An optional traced `mobility` [R, R] override (like the `breakpoints`
-override) lets mobility sweeps share one compilation.
+override) lets mobility sweeps share one compilation. A geography (a
+length-R population and length-R day-0 counts in the `EpiModelConfig`)
+replaces the even split population / R and the single seeded region, and
+couples each region to the prevalence x_q / P_q of the places its contacts
+happen (`coupling_matrix`).
 """
 
 from __future__ import annotations
@@ -43,6 +47,7 @@ from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.epi.spec import (
     CompartmentalModel,
@@ -62,8 +67,49 @@ def mobility_matrix(model: CompartmentalModel, mobility=None) -> jax.Array:
     return jnp.asarray(mob, jnp.float32)
 
 
+def _region_vector(model: CompartmentalModel, value, what: str) -> jax.Array:
+    """A geography's [R] per-region values as f32, checked against R."""
+    vec = jnp.asarray(value, jnp.float32)
+    if vec.shape != (model.n_regions,):
+        raise ValueError(
+            f"{what} has shape {vec.shape}; model {model.name!r} has "
+            f"{model.n_regions} regions"
+        )
+    return vec
+
+
+def region_population(model: CompartmentalModel, population):
+    """People in each region: a length-R population (a geography) is per
+    region as it stands; a scalar total is split evenly, population / R."""
+    if np.ndim(population):
+        return _region_vector(model, population, "population")
+    return population / model.n_regions
+
+
+def coupling_matrix(model: CompartmentalModel, mobility,
+                    population) -> jax.Array:
+    """The [R, R] matrix a coupled row contracts with.
+
+    Without a geography it is the mobility M: coupled_r = sum_q M[r, q] x_q,
+    every region holding the same P/R. With a geography's per-region
+    populations, region r's contacts in q meet q's prevalence x_q / P_q;
+    the coupled row carries that prevalence at region r's own scale,
+    P_r * sum_q M[r, q] x_q / P_q, so a hazard's x / P_r reads
+    sum_q M[r, q] x_q / P_q. The matrix is then M[r, q] P_r / P_q, which is
+    M itself, bit for bit, where the populations are equal.
+    """
+    mob = mobility_matrix(model, mobility)
+    if not np.ndim(population):
+        return mob
+    pop = region_population(model, population)
+    return mob * (pop[:, None] / pop[None, :])
+
+
 def _seed_vector(model: CompartmentalModel, value) -> jax.Array:
-    """[R] day-0 seed counts: `value` in `seed_region`, zero elsewhere."""
+    """[R] day-0 seed counts: a length-R value (a geography) as it stands,
+    a scalar in `seed_region` with zero elsewhere."""
+    if np.ndim(value):
+        return _region_vector(model, value, "day-0 counts")
     return (
         jnp.zeros((model.n_regions,), jnp.float32)
         .at[model.seed_region]
@@ -76,8 +122,10 @@ def initial_state(
 ) -> jax.Array:
     """Spec step 1: theta [..., n_params] -> state [..., total_state].
 
-    Metapop specs seed region `seed_region` with (a0, r0, d0); every other
-    region starts fully susceptible at population / R.
+    Metapop specs take a geography where `cfg` carries one: a length-R
+    population and length-R day-0 counts are per region. Without one, region
+    `seed_region` is seeded with the scalar (a0, r0, d0) and every region
+    holds population / R, the others fully susceptible.
     """
     theta = jnp.asarray(theta, jnp.float32)
     if not model.is_regional:
@@ -95,7 +143,7 @@ def initial_state(
     pc = tuple(theta[..., k : k + 1] for k in range(model.n_params))
     rows = model.initial_rows(
         pc,
-        cfg.population / R,
+        region_population(model, cfg.population),
         _seed_vector(model, cfg.a0),
         _seed_vector(model, cfg.r0),
         _seed_vector(model, cfg.d0),
@@ -186,8 +234,11 @@ def hazards(
     Metapop specs evaluate all regions at once: channel rows carry a trailing
     region axis, parameters broadcast as [..., 1] rows, and each coupled
     compartment contributes one mobility-weighted mass row via a single
-    [R, R] contraction. `mobility` optionally overrides the spec's static
-    matrix with a traced [R, R] value (mobility sweeps share one compile).
+    [R, R] contraction (`coupling_matrix`), under the named scope
+    `epi.couple`. `mobility` optionally overrides the spec's static matrix
+    with a traced [R, R] value (mobility sweeps share one compile);
+    `population` is a scalar total or a geography's [R] per-region row
+    (`region_population`), which couples prevalence in place of mass.
     """
     if not model.is_regional:
         sc = tuple(state[..., k] for k in range(model.n_state))
@@ -200,15 +251,17 @@ def hazards(
     st = state.reshape(batch + (R, C))
     sc = tuple(st[..., k] for k in range(C))  # each [..., R]
     pc = tuple(theta[..., k : k + 1] for k in range(model.n_params))
-    mob = mobility_matrix(model, mobility)
     # full f32: a TPU runs f32 contractions in one bf16 pass by default,
     # which would blur the coupled mass to ~3 significant digits
-    coupled = tuple(
-        jnp.einsum("rq,...q->...r", mob, st[..., j],
-                   precision=jax.lax.Precision.HIGHEST)
-        for j in model.coupled_idx
-    )
-    rows = model.hazard_rows(sc + coupled, pc, population / R)
+    with jax.named_scope("epi.couple"):
+        mob = coupling_matrix(model, mobility, population)
+        coupled = tuple(
+            jnp.einsum("rq,...q->...r", mob, st[..., j],
+                       precision=jax.lax.Precision.HIGHEST)
+            for j in model.coupled_idx
+        )
+    rows = model.hazard_rows(
+        sc + coupled, pc, region_population(model, population))
     h = jnp.stack(
         [jnp.broadcast_to(r, batch + (R,)) for r in rows], axis=-1
     )  # [..., R, T]

@@ -21,9 +21,18 @@ registered default is R=4 on a ring (each region keeps 90% of contacts
 local, 5% to each ring neighbour); `repro.epi.spec.regionalize` rescales
 it to any R (the 100-region campaign example in the README).
 
-Seeding: region `seed_region` (0) receives the dataset's day-0 counts
-exactly as single-region SEIR does; every other region starts fully
-susceptible at P/R.
+Populations and seeding: without a geography, region `seed_region` (0)
+receives the dataset's day-0 counts exactly as single-region SEIR does, and
+every region holds P/R, the others fully susceptible. A dataset with a
+geography (the bundled `usa_states`: 51 regions, per-state populations and
+day-0 counts, a gravity mobility matrix) sets P_r and the seeds of every
+region, and its matrix replaces M unless the run's config sets one. Region
+r's contacts in q then meet q's prevalence, and the exposure hazard reads
+
+  S_r -> E_r   beta * S_r * sum_q M[r, q] * I_q / P_q
+
+(the engine hands this body the coupled row P_r * sum_q M[r, q] I_q / P_q,
+`engine.coupling_matrix`); with every P_q = P/R the two forms agree.
 """
 
 from __future__ import annotations

@@ -51,9 +51,15 @@ region holding the mobility-weighted mass sum_q mobility[r][q] * x_q, so a
 metapop-aware `hazard_rows` receives n_state local rows followed by
 len(coupled) coupled rows and stays row-level (the same body runs in the XLA
 engine, where rows carry a trailing region axis, and in the Pallas kernel,
-where regions unroll into separate VREG rows at trace time). Each region
-holds population / R people; the dataset's (a0, r0, d0) day-0 counts seed
-`seed_region` only, every other region starting fully susceptible.
+where regions unroll into separate VREG rows at trace time). Without a
+geography each region holds population / R people and the dataset's scalar
+(a0, r0, d0) day-0 counts seed `seed_region` only, every other region
+starting fully susceptible. A dataset with a geography (`CountryData` with
+length-R population and day-0 counts, and its own mobility matrix) gives
+each region its own population P_r and seeds, and its matrix replaces the
+spec's unless `ABCConfig.mobility` is set; its coupled rows then carry the
+prevalence of the places region r's contacts happen, at region r's scale:
+P_r * sum_q mobility[r][q] * x_q / P_q (`engine.coupling_matrix`).
 """
 
 from __future__ import annotations
@@ -187,8 +193,9 @@ class CompartmentalModel:
     #: hazard reads its force-of-infection mass from these instead of the
     #: local rows
     coupled: Tuple[str, ...] = ()
-    #: region seeded with the dataset's (a0, r0, d0) day-0 counts; all other
-    #: regions start fully susceptible at population / n_regions
+    #: region seeded with the dataset's scalar (a0, r0, d0) day-0 counts;
+    #: all other regions start fully susceptible at population / n_regions.
+    #: Unused with a geography, whose counts and populations are per region.
     seed_region: int = 0
 
     def __post_init__(self):
@@ -576,10 +583,13 @@ EMPTY_SCHEDULE = InterventionSchedule(
 class EpiModelConfig:
     """Static simulation configuration (shared across all registry models)."""
 
-    population: float  # P — total population at day 0
+    # P — total population at day 0, or a geography's length-R populations
+    # (metapop specs only; see repro.epi.engine.region_population)
+    population: float
     num_days: int  # T — simulation horizon (paper uses 49 for fitting)
     # initial observed values (A0, R0, D0) at day 0; the spec's initial-state
-    # rule decides how they seed the compartments
+    # rule decides how they seed the compartments. A geography gives them
+    # per region, as length-R sequences.
     a0: float = 100.0
     r0: float = 0.0
     d0: float = 0.0

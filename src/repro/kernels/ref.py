@@ -45,10 +45,10 @@ def abc_sim_distance_ref(
     seed,  # uint32 scalar
     observed: jax.Array,  # [n_observed, T] f32
     *,
-    population: float,
-    a0: float,
-    r0: float,
-    d0: float,
+    population,  # scalar total, or a geography's [R] per-region values
+    a0,  # scalar day-0 counts, or a geography's [R] rows (so r0, d0)
+    r0,
+    d0,
     model: CompartmentalModel | None = None,
     schedule=None,  # InterventionSchedule; theta carries its scale columns
     summary=None,  # SummarySpec / registry name / None (identity)
@@ -59,7 +59,9 @@ def abc_sim_distance_ref(
     observed. Default (identity, euclidean) is the paper's raw Euclidean and
     reduces bit-exactly to the legacy running sum-of-squares; any other pair
     uses the same generalized running accumulator the kernel lowers
-    (core.summaries.running_day), pinning kernel-vs-oracle parity per pair."""
+    (core.summaries.running_day), pinning kernel-vs-oracle parity per pair.
+    A metapop model takes a geography as the engine does: per-region
+    populations and day-0 counts, and its matrix as `mobility`."""
     if model is None:
         from repro.epi.models import DEFAULT_MODEL as model  # noqa: N811
     spec = get_summary(summary)

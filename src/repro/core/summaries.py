@@ -368,7 +368,11 @@ def running_day(
     """One day of the generalized running-distance accumulator (module
     docstring recurrence), tensor layout. Shared by the fused XLA scan and
     the kernel oracle; the Pallas kernel body is the traced-select twin
-    (kernels/abc_sim.py) validated against this via ref.py parity tests."""
+    (kernels/abc_sim.py) validated against this via ref.py parity tests.
+
+    The channel axes are the trailing `weights.ndim` axes: [n_obs] as a
+    rule, or [R, n_obs] for a regional scan that keeps its observed rows
+    unflattened, summed in the same region-major order."""
     # spec is always a concrete SummarySpec here (only the Pallas kernel
     # needs traced selects), so non-cumulative specs skip the cum update
     # entirely — the carry passes through untouched. A cumulative channel's
@@ -384,7 +388,8 @@ def running_day(
     s = jnp.log1p(jnp.maximum(binv, 0.0)) if spec.log1p else binv
     diff = s - obs_t
     term = jnp.abs(diff) if kind.power == 1 else diff * diff
-    acc = acc + flush_t * jnp.sum(weights * term, axis=-1)
+    chan_axes = tuple(range(-jnp.ndim(weights), 0))
+    acc = acc + flush_t * jnp.sum(weights * term, axis=chan_axes)
     binv = binv * (1.0 - flush_t)
     return cum, binv, acc
 

@@ -20,19 +20,28 @@ Four entry points mirror the original module:
 The Pallas path (`repro.kernels.abc_sim`) inlines the same spec into a fused
 VMEM-resident kernel; this module is the paper-faithful XLA reference.
 
+The engine's logic is row-level: `hazard_rows`, `drain_and_apply` and
+`effective_param_rows` take one array per compartment, transition or
+parameter, the layout the Pallas kernel body uses too. The fused scan of
+`simulate_observed_lowmem` carries its state as those rows (`[B]` each, or
+`[B, R]` for a regional spec) and stacks it once, after the last day. The
+tensor wrappers `hazards`, `apply_transitions` and `tau_leap_step` stack and
+split around the rows; they serve `simulate` (the `xla` backend's
+trajectory), `simulate_features`, the kernel oracle and the tests.
+
 All entry points optionally take an `InterventionSchedule`: theta then
 carries extra per-window scale columns and each day's hazards are computed
 with that day's window-effective parameters (`effective_param_rows` — the
 row-level helper the Pallas kernel shares, like `drain_and_apply`).
 
 Spatial metapopulation specs (`model.is_regional`) take a tensor region
-path: state/noise/observed flatten region-major to `[..., R * n]` (see the
-spec module docstring), channel rows carry a trailing region axis `[..., R]`
-with parameter rows broadcast as `[..., 1]`, and the coupled-mass rows are a
-single `[R, R] @ [..., R]` einsum per coupled compartment — so a 100-region
-model costs one contraction per day, not an unrolled R^2 expression. The
-flat R=1 uncoupled branch is untouched code, keeping every registered model
-bit-identical to pre-metapop releases (pinned by tests/test_metapop.py).
+path: state/noise/observed tensors flatten region-major to `[..., R * n]`
+(see the spec module docstring), channel rows carry a trailing region axis
+`[..., R]` with parameter rows broadcast as `[..., 1]`, and the coupled-mass
+rows are a single `[R, R] @ [..., R]` einsum per coupled compartment — so a
+100-region model costs one contraction per day, not an unrolled R^2
+expression. Every registered R = 1 model stays bit-identical to
+pre-metapop releases (pinned by tests/test_metapop.py).
 An optional traced `mobility` [R, R] override (like the `breakpoints`
 override) lets mobility sweeps share one compilation. A geography (a
 length-R population and length-R day-0 counts in the `EpiModelConfig`)
@@ -117,10 +126,11 @@ def _seed_vector(model: CompartmentalModel, value) -> jax.Array:
     )
 
 
-def initial_state(
+def initial_state_rows(
     model: CompartmentalModel, theta: jax.Array, cfg: EpiModelConfig
-) -> jax.Array:
-    """Spec step 1: theta [..., n_params] -> state [..., total_state].
+) -> tuple:
+    """Spec step 1 as compartment rows: theta [..., n_params] -> C rows, each
+    [...] for an R = 1 spec or [..., R] for a regional one.
 
     Metapop specs take a geography where `cfg` carries one: a length-R
     population and length-R day-0 counts are per region. Without one, region
@@ -128,31 +138,55 @@ def initial_state(
     holds population / R, the others fully susceptible.
     """
     theta = jnp.asarray(theta, jnp.float32)
+    shape = theta.shape[:-1]
     if not model.is_regional:
-        pc = tuple(theta[..., k] for k in range(model.n_params))
         rows = model.initial_rows(
-            pc,
+            param_rows(model, theta),
             cfg.population,
             jnp.asarray(cfg.a0, jnp.float32),
             jnp.asarray(cfg.r0, jnp.float32),
             jnp.asarray(cfg.d0, jnp.float32),
         )
-        return jnp.stack(list(rows), axis=-1).astype(jnp.float32)
-    R, C = model.n_regions, model.n_state
-    batch = theta.shape[:-1]
-    pc = tuple(theta[..., k : k + 1] for k in range(model.n_params))
-    rows = model.initial_rows(
-        pc,
-        region_population(model, cfg.population),
-        _seed_vector(model, cfg.a0),
-        _seed_vector(model, cfg.r0),
-        _seed_vector(model, cfg.d0),
+    else:
+        shape = shape + (model.n_regions,)
+        rows = model.initial_rows(
+            param_rows(model, theta),
+            region_population(model, cfg.population),
+            _seed_vector(model, cfg.a0),
+            _seed_vector(model, cfg.r0),
+            _seed_vector(model, cfg.d0),
+        )
+    return tuple(
+        jnp.broadcast_to(jnp.asarray(r, jnp.float32), shape) for r in rows
     )
-    rows = [
-        jnp.broadcast_to(jnp.asarray(r, jnp.float32), batch + (R,)) for r in rows
-    ]
-    # [..., R, C] -> region-major flat [..., R*C]
-    return jnp.stack(rows, axis=-1).reshape(batch + (R * C,)).astype(jnp.float32)
+
+
+def stack_state(model: CompartmentalModel, rows) -> jax.Array:
+    """C compartment rows -> the state tensor [..., total_state], flattened
+    region-major for a regional spec (channel r * n_state + c)."""
+    state = jnp.stack(list(rows), axis=-1)
+    if not model.is_regional:
+        return state
+    return state.reshape(state.shape[:-2] + (model.total_state,))
+
+
+def initial_state(
+    model: CompartmentalModel, theta: jax.Array, cfg: EpiModelConfig
+) -> jax.Array:
+    """Spec step 1: theta [..., n_params] -> state [..., total_state]
+    (`initial_state_rows`, stacked)."""
+    return stack_state(model, initial_state_rows(model, theta, cfg))
+
+
+def param_rows(model: CompartmentalModel, theta: jax.Array, width=None):
+    """Split theta [..., width] into parameter rows: [...] each at R = 1,
+    [..., 1] for a regional spec (they broadcast over the region axis).
+    `width` defaults to n_params; a schedule's widened theta passes its
+    `param_width`."""
+    width = model.n_params if width is None else width
+    if not model.is_regional:
+        return tuple(theta[..., k] for k in range(width))
+    return tuple(theta[..., k : k + 1] for k in range(width))
 
 
 def effective_param_rows(
@@ -222,6 +256,47 @@ def _breakpoint_scalars(schedule, breakpoints):
     return tuple(bp[i] for i in range(schedule.n_windows))
 
 
+def hazard_rows(
+    model: CompartmentalModel, sc, pc, population, mobility=None
+) -> list:
+    """Row-level transition rates: compartment rows `sc` and parameter rows
+    `pc` (`param_rows` layout) -> one clamped hazard row per transition,
+    each the shape of a compartment row.
+
+    Regional specs append one mobility-weighted mass row per coupled
+    compartment, each a single [R, R] contraction of that compartment's
+    [..., R] row (`coupling_matrix`) under the named scope `epi.couple`.
+    """
+    sc = tuple(sc)
+    shape = sc[0].shape
+    if model.is_regional:
+        # full f32: a TPU runs f32 contractions in one bf16 pass by default,
+        # which would blur the coupled mass to ~3 significant digits
+        with jax.named_scope("epi.couple"):
+            mob = coupling_matrix(model, mobility, population)
+            coupled = tuple(
+                jnp.einsum("rq,...q->...r", mob, sc[j],
+                           precision=jax.lax.Precision.HIGHEST)
+                for j in model.coupled_idx
+            )
+        sc = sc + coupled
+        population = region_population(model, population)
+    # Hazards are rates of counting processes; they cannot be negative.
+    return [
+        jnp.maximum(jnp.broadcast_to(h, shape), 0.0)
+        for h in model.hazard_rows(sc, pc, population)
+    ]
+
+
+def state_rows(model: CompartmentalModel, state: jax.Array) -> tuple:
+    """The state tensor [..., total_state] -> C compartment rows (the
+    inverse of `stack_state`)."""
+    if model.is_regional:
+        state = state.reshape(
+            state.shape[:-1] + (model.n_regions, model.n_state))
+    return tuple(state[..., k] for k in range(model.n_state))
+
+
 def hazards(
     model: CompartmentalModel,
     state: jax.Array,
@@ -229,43 +304,21 @@ def hazards(
     population: float,
     mobility=None,
 ) -> jax.Array:
-    """Transition rates: state [..., total_state] -> h [..., total_transitions].
+    """Transition rates: state [..., total_state] -> h [..., total_transitions]
+    (region-major: slot r * n_transitions + k is region r's transition k).
 
-    Metapop specs evaluate all regions at once: channel rows carry a trailing
-    region axis, parameters broadcast as [..., 1] rows, and each coupled
-    compartment contributes one mobility-weighted mass row via a single
-    [R, R] contraction (`coupling_matrix`), under the named scope
-    `epi.couple`. `mobility` optionally overrides the spec's static matrix
-    with a traced [R, R] value (mobility sweeps share one compile);
-    `population` is a scalar total or a geography's [R] per-region row
-    (`region_population`), which couples prevalence in place of mass.
+    The tensor wrapper around `hazard_rows`. `mobility` optionally overrides
+    the spec's static matrix with a traced [R, R] value (mobility sweeps
+    share one compile); `population` is a scalar total or a geography's [R]
+    per-region row (`region_population`), which couples prevalence in place
+    of mass.
     """
+    rows = hazard_rows(model, state_rows(model, state),
+                       param_rows(model, theta), population, mobility)
+    h = jnp.stack(rows, axis=-1)
     if not model.is_regional:
-        sc = tuple(state[..., k] for k in range(model.n_state))
-        pc = tuple(theta[..., k] for k in range(model.n_params))
-        h = jnp.stack(list(model.hazard_rows(sc, pc, population)), axis=-1)
-        # Hazards are rates of counting processes; they cannot be negative.
-        return jnp.maximum(h, 0.0)
-    R, C, T = model.n_regions, model.n_state, model.n_transitions
-    batch = state.shape[:-1]
-    st = state.reshape(batch + (R, C))
-    sc = tuple(st[..., k] for k in range(C))  # each [..., R]
-    pc = tuple(theta[..., k : k + 1] for k in range(model.n_params))
-    # full f32: a TPU runs f32 contractions in one bf16 pass by default,
-    # which would blur the coupled mass to ~3 significant digits
-    with jax.named_scope("epi.couple"):
-        mob = coupling_matrix(model, mobility, population)
-        coupled = tuple(
-            jnp.einsum("rq,...q->...r", mob, st[..., j],
-                       precision=jax.lax.Precision.HIGHEST)
-            for j in model.coupled_idx
-        )
-    rows = model.hazard_rows(
-        sc + coupled, pc, region_population(model, population))
-    h = jnp.stack(
-        [jnp.broadcast_to(r, batch + (R,)) for r in rows], axis=-1
-    )  # [..., R, T]
-    return jnp.maximum(h, 0.0).reshape(batch + (R * T,))
+        return h
+    return h.reshape(h.shape[:-2] + (model.total_transitions,))
 
 
 def drain_and_apply(model: CompartmentalModel, sc, raw_counts):
@@ -306,18 +359,12 @@ def apply_transitions(
     Metapop specs drain per region: the channel/count rows carry a trailing
     region axis, so the shared row-level clamp logic applies unchanged.
     """
-    if not model.is_regional:
-        sc = (state[..., k] for k in range(model.n_state))
-        raw = [n_raw[..., k] for k in range(model.n_transitions)]
-        return jnp.stack(drain_and_apply(model, sc, raw), axis=-1)
-    R, C, T = model.n_regions, model.n_state, model.n_transitions
-    batch = state.shape[:-1]
-    st = state.reshape(batch + (R, C))
-    nr = n_raw.reshape(batch + (R, T))
-    sc = (st[..., k] for k in range(C))
-    raw = [nr[..., k] for k in range(T)]
-    out = drain_and_apply(model, sc, raw)  # rows [..., R]
-    return jnp.stack(out, axis=-1).reshape(batch + (R * C,))
+    T = model.n_transitions
+    if model.is_regional:
+        n_raw = n_raw.reshape(n_raw.shape[:-1] + (model.n_regions, T))
+    raw = [n_raw[..., k] for k in range(T)]
+    return stack_state(
+        model, drain_and_apply(model, state_rows(model, state), raw))
 
 
 def tau_leap_step(
@@ -405,6 +452,27 @@ def simulate_observed(
     return jnp.swapaxes(obs, -1, -2)  # [B, total_obs, T]
 
 
+def _noise_row(model: CompartmentalModel, z: jax.Array, k: int) -> jax.Array:
+    """Transition k's row of a day's noise z [..., total_transitions]: the
+    column k at R = 1, the strided slots r * n_transitions + k of every
+    region for a regional spec (the numbers `tau_leap_step` reads)."""
+    if not model.is_regional:
+        return z[..., k]
+    T = model.n_transitions
+    return jax.lax.slice_in_dim(z, k, z.shape[-1], stride=T, axis=z.ndim - 1)
+
+
+def _observed_channels(model: CompartmentalModel, rows, pool: int):
+    """One day's observed channels from compartment rows: [..., n_obs] at
+    R = 1 or where the summary pools the regions (summed over them, as
+    `core.summaries.pool_channels` does), else [..., R, n_obs], whose
+    row-major order is the region-major `total_observed_idx` order."""
+    obs = jnp.stack([rows[c] for c in model.observed_idx], axis=-1)
+    if model.is_regional and pool > 1:
+        return jnp.sum(obs, axis=-2)
+    return obs
+
+
 def simulate_observed_lowmem(
     model: CompartmentalModel,
     theta: jax.Array,
@@ -423,13 +491,23 @@ def simulate_observed_lowmem(
     The beyond-paper memory optimization (DESIGN.md §2): never materialize
     the [B, n_obs, T] trajectory; fold the (summary, distance) pair into the
     day scan via the generalized running accumulator (core.summaries).
-    Returns (distance [B], final state).
+    Returns (distance [B], final state [B, total_state]).
+
+    The scan carries the state as C compartment rows ([B] each, [B, R] for
+    a regional spec) and works on rows throughout: `hazard_rows`, the
+    tau-leap floor per transition row, `drain_and_apply`, theta split into
+    parameter rows once before the scan, observed channels picked by static
+    index. No day stacks or re-splits the state or the hazards; only the
+    observed rows are stacked, into the distance. Every element gets the
+    operations of `simulate`'s tensor path in the same order, and the noise
+    is the same [B, total_transitions] draw sliced per transition, so the
+    final state equals `simulate(...)[:, -1]` bit for bit (pinned by
+    tests/test_scan_layout.py).
 
     `summary` is a SummarySpec / registry name / None; the default
     (identity, "euclidean") reduces to exactly the legacy running
     sum-of-squares — flush and weights are constant 1.0 and every transform
-    select is constant-false, so outputs stay bit-identical to pre-summary
-    releases (pinned by tests/test_summaries.py).
+    select is constant-false (pinned by tests/test_summaries.py).
 
     This is the pure-XLA analogue of the Pallas kernel; the kernel
     additionally keeps the whole loop in VMEM.
@@ -438,7 +516,6 @@ def simulate_observed_lowmem(
         get_distance_kind,
         get_summary,
         lower_summary,
-        pool_channels,
         pool_factor,
         running_day,
         running_finalize,
@@ -449,43 +526,56 @@ def simulate_observed_lowmem(
     lowered = lower_summary(spec, distance, observed, n_regions=model.n_regions)
     pool = pool_factor(spec, model.n_regions)
     theta = jnp.asarray(theta, jnp.float32)
-    batch_shape = theta.shape[:-1]
-    obs_idx = model.total_observed_idx
-    state0 = initial_state(model, theta, cfg)
-    # derive from state0 so the carries inherit its varying mesh axes when
+    noise_shape = theta.shape[:-1] + (model.total_transitions,)
+    if schedule is None or schedule.is_empty:
+        sched, width = None, model.n_params
+    else:
+        sched, width = schedule.shape(model), schedule.param_width(model)
+    pc = param_rows(model, theta, width)
+    rows0 = initial_state_rows(model, theta, cfg)
+    # derive from the state so the carries inherit its varying mesh axes when
     # this runs inside shard_map (scan carries must have uniform vma types)
-    acc0 = state0[..., 0] * 0.0
+    acc0 = (rows0[0][..., 0] if model.is_regional else rows0[0]) * 0.0
     # [..., n_chan] cum/bin carries (region-pooled channels pool the sims)
-    chan0 = pool_channels(state0[..., obs_idx], pool) * 0.0
+    chan0 = _observed_channels(model, rows0, pool) * 0.0
     obs_by_day = jnp.swapaxes(lowered.obs_summary, 0, 1)  # [T, n_chan]
+    weights = lowered.weights
+    if model.is_regional and pool == 1:
+        # unpooled regional channels stay [R, n_obs]: no day interleaves
+        # the observed rows into the flat region-major layout
+        chan = (model.n_regions, model.n_observed)
+        obs_by_day = obs_by_day.reshape((cfg.num_days,) + chan)
+        weights = weights.reshape(chan)
     bp = _breakpoint_scalars(schedule, breakpoints)
 
     def step(carry, inp):
-        state, cum, binv, acc = carry
+        rows, cum, binv, acc = carry
         day, obs_t, flush_t = inp
         z = jax.random.normal(
-            jax.random.fold_in(key, day),
-            batch_shape + (model.total_transitions,),
-            jnp.float32,
-        )
-        th_d = effective_theta(model, schedule, theta, day, bp)
-        nxt = tau_leap_step(model, state, th_d, z, cfg.population, mobility)
+            jax.random.fold_in(key, day), noise_shape, jnp.float32)
+        pd = effective_param_rows(model, sched, pc, day, bp)
+        h = hazard_rows(model, rows, pd, cfg.population, mobility)
+        raw = [
+            jnp.floor(hk + jnp.sqrt(hk) * _noise_row(model, z, k))
+            for k, hk in enumerate(h)
+        ]
+        rows = tuple(drain_and_apply(model, rows, raw))
         cum, binv, acc = running_day(
-            spec, kind, lowered.weights,
-            pool_channels(nxt[..., obs_idx], pool), obs_t, flush_t,
-            cum, binv, acc,
+            spec, kind, weights, _observed_channels(model, rows, pool),
+            obs_t, flush_t, cum, binv, acc,
         )
-        return (nxt, cum, binv, acc), None
+        return (rows, cum, binv, acc), None
 
     days = jnp.arange(cfg.num_days)
     # `unroll` is the xla_fused chunking knob searched by the autotuner
     # (repro.core.tuning): pure scheduling, the day streams are unchanged so
     # distances stay bit-identical across unroll factors (pinned by tests)
-    (state_f, _, _, acc_f), _ = jax.lax.scan(
-        step, (state0, chan0, chan0, acc0), (days, obs_by_day, lowered.flush),
+    (rows_f, _, _, acc_f), _ = jax.lax.scan(
+        step, (rows0, chan0, chan0, acc0), (days, obs_by_day, lowered.flush),
         unroll=max(1, int(unroll)),
     )
-    return running_finalize(kind, lowered.mean_scale, acc_f), state_f
+    return running_finalize(kind, lowered.mean_scale, acc_f), stack_state(
+        model, rows_f)
 
 
 def simulate_features(

@@ -7,8 +7,9 @@ tests compile the kernel and the paper-size wave loop with
 that the kernel is in the program (`tpu_custom_call`) and that the program
 fits the chip's 16 GB. The sharded wave loop and SMC round are compiled for
 all four chips, and their per-wave accept-count all-reduce must stay inside
-the loop that tests it. Nothing runs, so they say nothing about results or
-times.
+the loop that tests it. The `xla_fused` day scan at the paper's size must
+carry its state as rows, with no concatenate of the state in its loop.
+Nothing runs, so they say nothing about results or times.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process may load the TPU compiler's library at a time, and every
@@ -30,9 +31,10 @@ from repro.core.abc import ABCConfig, ABCState, make_simulator, make_wave_runner
 from repro.core.priors import schedule_prior
 from repro.core.smc import SMCConfig, make_sharded_smc_round_fn
 from repro.core.summaries import N_FLAGS
+from repro.epi import engine
 from repro.epi.data import get_dataset
 from repro.epi.models import get_model
-from repro.epi.spec import InterventionSchedule, regionalize
+from repro.epi.spec import EpiModelConfig, InterventionSchedule, regionalize
 from repro.kernels import abc_sim, ops
 
 HBM_BYTES = 16e9  # one v5e chip
@@ -140,6 +142,57 @@ def test_pallas_device_wave_loop_compiles_for_v5e(one_chip):
         arg(jnp.float32(cfg.tolerance)), None,
     ).compile()
     _check_compiled(compiled)
+
+
+def _computations(hlo: str) -> dict:
+    """Optimized-HLO computation name -> its text."""
+    comps, name = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%(\S+) ", line)
+        if head and line.rstrip().endswith("{"):
+            name = head.group(1)
+            comps[name] = ""
+        elif name is not None:
+            comps[name] += line + "\n"
+    return comps
+
+
+def _loop_text(hlo: str) -> str:
+    """The while bodies and every computation they call, transitively."""
+    comps = _computations(hlo)
+    todo = re.findall(r"body=%([\w.\-]+)", hlo)
+    seen = set()
+    while todo:
+        name = todo.pop()
+        if name not in seen:
+            seen.add(name)
+            todo += re.findall(r"(?:calls|to_apply|body|condition)=%([\w.\-]+)",
+                               comps[name])
+    return "".join(comps[n] for n in seen)
+
+
+def test_fused_day_scan_concatenates_no_state_for_v5e(one_chip):
+    """The `xla_fused` day scan at the paper's size carries rows: its loop
+    holds no concatenate into the [B, 6] state or the [B, 5] hazards."""
+    spec = get_model("siard")
+    batch = 100_000
+    cfg = EpiModelConfig(population=6e7, num_days=DAYS, a0=100.0)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda th, key, obs: engine.simulate_observed_lowmem(
+            spec, th, key, cfg, obs)
+    ).lower(
+        arg((batch, spec.n_params), jnp.float32), arg((2,), jnp.uint32),
+        arg((spec.n_observed, DAYS), jnp.float32),
+    ).compile()
+    loop = _loop_text(compiled.as_text())
+    assert "fusion" in loop
+    for width in (spec.n_state, spec.n_transitions):
+        assert not re.search(
+            rf"= f32\[{batch},{width}\]\S* concatenate\(", loop), width
 
 
 @pytest.fixture(scope="module")
